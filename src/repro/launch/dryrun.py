@@ -1,12 +1,13 @@
-import os
-os.environ["XLA_FLAGS"] = "--xla_force_host_platform_device_count=512"
 """Multi-pod dry-run: prove every (architecture × input shape × mesh)
 cell lowers AND compiles under the production sharding config, and
 extract the roofline terms from the compiled artifact.
 
-MUST be run as its own process (the XLA_FLAGS line above precedes every
-jax import — jax locks the device count on first init). Never set this
-flag globally: smoke tests and benches see 1 device.
+MUST be run as its own process (``python -m repro.launch.dryrun``): run
+as the main module it appends ``--xla_force_host_platform_device_count=
+512`` to ``XLA_FLAGS`` before any jax import, since jax locks the device
+count on first init. Importing the module sets nothing, so smoke tests
+and benches keep seeing 1 device. It exits 1 when any arch or cell
+fails.
 
 Per cell it records into results/dryrun/<cell>.json:
   * compiled.memory_analysis()  — per-device bytes (proves it fits)
@@ -31,6 +32,13 @@ reduced training step, partitions it, and writes the versioned plan
 artifact next to the dry-run results — the op-level counterpart of the
 mesh cells above.
 """
+import os
+
+if __name__ == "__main__":
+    os.environ["XLA_FLAGS"] = " ".join(filter(None, (
+        os.environ.get("XLA_FLAGS"),
+        "--xla_force_host_platform_device_count=512")))
+
 import argparse       # noqa: E402
 import json           # noqa: E402
 import re             # noqa: E402
@@ -442,7 +450,7 @@ def main() -> int:
                 print(f"[FAIL] {a}: {type(e).__name__}: {e}", flush=True)
         if args.metrics:
             _write_metrics(args.metrics, "dryrun_calibrate", records)
-        return 0
+        return 1 if any("error" in r for r in records.values()) else 0
 
     if args.pardnn:
         os.makedirs(args.out, exist_ok=True)
@@ -523,6 +531,7 @@ def main() -> int:
         return 0
 
     os.makedirs(args.out, exist_ok=True)
+    failed = 0
     for a, s, m in cells:
         name = cell_name(a, s, m, args.tag)
         path = os.path.join(args.out, name + ".json")
@@ -552,8 +561,9 @@ def main() -> int:
                      f"{mem / 2**30:.1f}G" if mem else "compile-only")
         elif status == "FAIL":
             extra = res["error"][:200]
+            failed += 1
         print(f"[{status}] {name} ({res['wall_s']}s) {extra}", flush=True)
-    return 0
+    return 1 if failed else 0
 
 
 if __name__ == "__main__":

@@ -15,13 +15,25 @@ from __future__ import annotations
 
 import jax
 import numpy as np
-from jax.sharding import Mesh
+from jax.sharding import AxisType, Mesh
+
+
+def make_mesh(shape, axes, *, devices=None) -> Mesh:
+    """``jax.make_mesh`` with every axis ``Auto``.
+
+    The step builders place values with ``NamedSharding`` and
+    ``with_sharding_constraint`` and leave propagation to the compiler,
+    which only ``Auto`` axes allow (``jax.make_mesh`` defaults to
+    ``Explicit``)."""
+    return jax.make_mesh(tuple(shape), tuple(axes),
+                         axis_types=(AxisType.Auto,) * len(axes),
+                         devices=devices)
 
 
 def make_production_mesh(*, multi_pod: bool = False) -> Mesh:
     shape = (2, 16, 16) if multi_pod else (16, 16)
     axes = ("pod", "data", "model") if multi_pod else ("data", "model")
-    return jax.make_mesh(shape, axes)
+    return make_mesh(shape, axes)
 
 
 def make_host_mesh(data: int | None = None, model: int = 1,
@@ -32,8 +44,8 @@ def make_host_mesh(data: int | None = None, model: int = 1,
         data = n // (model * pod)
     assert pod * data * model <= n, (pod, data, model, n)
     if pod > 1:
-        return jax.make_mesh((pod, data, model), ("pod", "data", "model"))
-    return jax.make_mesh((data, model), ("data", "model"))
+        return make_mesh((pod, data, model), ("pod", "data", "model"))
+    return make_mesh((data, model), ("data", "model"))
 
 
 def mesh_num_chips(mesh: Mesh) -> int:

@@ -42,9 +42,11 @@ def main():
 
     from repro.checkpoint.manager import CheckpointManager
     from repro.configs import get_config, reduced
+    from repro.launch.compile_cache import enable_compile_cache
     from repro.models import init_params
     from repro.serving import Request, ServingEngine, partition_for_serving
 
+    enable_compile_cache()
     cfg = get_config(args.arch)
     if args.reduced:
         cfg = reduced(cfg)

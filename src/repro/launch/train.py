@@ -6,29 +6,15 @@
 On a real TPU pod each host runs this same binary (jax.distributed
 initializes from the TPU environment); the mesh is built from whatever
 devices exist, so a restart after losing a pod re-shards automatically
-(elastic). XLA latency-hiding flags for collective/compute overlap are
-applied unless already set.
+(elastic).
 
 Fault tolerance: async checkpoints every --ckpt-every, SIGTERM-safe
 final checkpoint, non-finite-step skipping, straggler watchdog —
 see train/loop.py.
 """
-import os
+import argparse
 
-_XLA_PERF_FLAGS = (
-    "--xla_tpu_enable_async_collective_fusion=true "
-    "--xla_tpu_enable_async_collective_fusion_fuse_all_gather=true "
-    "--xla_tpu_overlap_compute_collective_tc=true "
-    "--xla_enable_async_all_gather=true "
-    "--xla_enable_async_collective_permute=true"
-)
-if "XLA_FLAGS" not in os.environ:
-    os.environ["XLA_FLAGS"] = _XLA_PERF_FLAGS  # TPU backends ignore unknown
-
-import argparse          # noqa: E402
-import dataclasses       # noqa: E402
-
-import jax               # noqa: E402
+import jax
 
 
 def main():
@@ -52,12 +38,14 @@ def main():
     from repro.checkpoint.manager import CheckpointManager
     from repro.configs import get_config, reduced
     from repro.data.pipeline import DataConfig, DataIterator
+    from repro.launch.compile_cache import enable_compile_cache
     from repro.launch.mesh import make_host_mesh
     from repro.models import init_params
     from repro.train.loop import LoopConfig, TrainLoop
     from repro.train.optimizer import AdamWConfig, init_state
     from repro.train.step import build_train_step
 
+    enable_compile_cache()
     cfg = get_config(args.arch)
     if args.reduced:
         cfg = reduced(cfg)

@@ -34,7 +34,6 @@ import jax.numpy as jnp
 import numpy as np
 from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 
-from repro.compat import shard_map
 from repro.core.graph import CostGraph
 from repro.core.emulator import emulate
 
@@ -224,7 +223,7 @@ def pipeline_apply(mesh: Mesh, layer_fn, stage_params, mask,
 
     pspec = jax.tree_util.tree_map(
         lambda _: P(stage_axis), stage_params)
-    out = shard_map(
+    out = jax.shard_map(
         stage_body, mesh=mesh,
         in_specs=(pspec, P(stage_axis), P()),
         out_specs=P(),

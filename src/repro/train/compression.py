@@ -60,15 +60,12 @@ def make_compressed_psum(mesh, axis_name: str = "pod", *,
 
     Returns ``fn(grads, errors) -> (reduced_grads, new_errors)`` with
     grads sharded over the axis, errors replicated on the way in (fresh
-    :func:`init_error_state`) and per-shard on the way out. Built on the
-    version-compat shim so it runs on both old and new JAX spellings of
-    shard_map.
+    :func:`init_error_state`) and per-shard on the way out.
     """
     from jax.sharding import PartitionSpec as P
-    from repro.compat import shard_map
     n = int(mesh.shape[axis_name])
     e_spec = P() if error_in_spec is None else error_in_spec
-    return shard_map(lambda g, e: ef_int8_psum(g, e, axis_name, n),
+    return jax.shard_map(lambda g, e: ef_int8_psum(g, e, axis_name, n),
                      mesh=mesh, in_specs=(P(axis_name), e_spec),
                      out_specs=(P(), P(axis_name)), check_vma=False)
 

@@ -6,6 +6,7 @@ import jax
 from repro.checkpoint.manager import CheckpointManager
 from repro.configs import get_config, reduced
 from repro.data.pipeline import DataConfig, DataIterator
+from repro.launch.mesh import make_mesh
 from repro.models import init_params
 from repro.train.loop import LoopConfig, TrainLoop
 from repro.train.optimizer import AdamWConfig, init_state
@@ -13,7 +14,7 @@ from repro.train.step import build_train_step
 
 
 def test_resume_matches_uninterrupted_run():
-    mesh = jax.make_mesh((1, 1), ("data", "model"))
+    mesh = make_mesh((1, 1), ("data", "model"))
     cfg = reduced(get_config("repro-lm-100m"))
     key = jax.random.PRNGKey(0)
     ocfg = AdamWConfig(warmup_steps=2, total_steps=40)

@@ -15,6 +15,7 @@ def test_dp_tp_train_step_matches_single_device():
         from repro.models import init_params
         from repro.train.step import build_train_step
         from repro.train.optimizer import AdamWConfig, init_state
+        from repro.launch.mesh import make_mesh
         cfg = reduced(get_config('granite-8b'))
         key = jax.random.PRNGKey(0)
         ocfg = AdamWConfig(warmup_steps=0, total_steps=10)
@@ -23,7 +24,7 @@ def test_dp_tp_train_step_matches_single_device():
         losses = []
         for shape, axes in [((1,1),('data','model')), ((2,2),('data','model')),
                             ((4,1),('data','model')), ((1,4),('data','model'))]:
-            mesh = jax.make_mesh(shape, axes)
+            mesh = make_mesh(shape, axes)
             params = init_params(cfg, key)
             opt = init_state(ocfg, params)
             built = build_train_step(cfg, mesh, ocfg, donate=False)
@@ -42,7 +43,8 @@ def test_pipeline_runtime_exact_fwd_and_grad():
         import jax, jax.numpy as jnp, numpy as np
         from repro.pipeline.pardnn_pp import (plan_stages, stack_stage_params,
                                               pipeline_apply)
-        mesh = jax.make_mesh((4,), ('stage',))
+        from repro.launch.mesh import make_mesh
+        mesh = make_mesh((4,), ('stage',))
         key = jax.random.PRNGKey(0)
         L, D, M, mb = 8, 8, 4, 2
         W = jax.random.normal(key, (L, D, D)) * 0.3
@@ -72,7 +74,8 @@ def test_compressed_psum_over_pod_axis():
         from jax.sharding import PartitionSpec as P
         from repro.train.compression import (make_compressed_psum,
                                              init_error_state)
-        mesh = jax.make_mesh((4,), ('pod',))
+        from repro.launch.mesh import make_mesh
+        mesh = make_mesh((4,), ('pod',))
         key = jax.random.PRNGKey(0)
         grads = {'w': jax.random.normal(key, (4, 32, 8))}
         errors = init_error_state({'w': jnp.zeros((32, 8))})
@@ -95,14 +98,14 @@ def test_elastic_checkpoint_restore_across_meshes():
         import jax, jax.numpy as jnp, numpy as np
         from jax.sharding import NamedSharding, PartitionSpec as P
         from repro.checkpoint.manager import CheckpointManager
-        mesh4 = jax.make_mesh((4,), ('model',))
+        from repro.launch.mesh import make_mesh
+        mesh4 = make_mesh((4,), ('model',))
         x = jax.device_put(jnp.arange(16.0).reshape(4, 4),
                            NamedSharding(mesh4, P('model', None)))
         with tempfile.TemporaryDirectory() as td:
             ck = CheckpointManager(td)
             ck.save(1, {'x': x})
-            mesh2 = jax.make_mesh((2,), ('model',),
-                                  devices=jax.devices()[:2])
+            mesh2 = make_mesh((2,), ('model',), devices=jax.devices()[:2])
             sh2 = {'x': NamedSharding(mesh2, P('model', None))}
             restored, _ = ck.restore({'x': x}, shardings=sh2)
             np.testing.assert_array_equal(np.asarray(restored['x']),

@@ -9,9 +9,8 @@ from repro.configs import ASSIGNED_ARCHS, get_config
 from repro.models.io_spec import params_spec
 from repro.sharding import rules
 
-# AbstractMesh takes a shape tuple of (name, size) pairs (JAX >= 0.4.35)
-MESH = AbstractMesh((("data", 16), ("model", 16)))
-MESH_MP = AbstractMesh((("pod", 2), ("data", 16), ("model", 16)))
+MESH = AbstractMesh((16, 16), ("data", "model"))
+MESH_MP = AbstractMesh((2, 16, 16), ("pod", "data", "model"))
 
 
 def _check_divisible(spec_tree, shape_tree, mesh):
