@@ -43,6 +43,7 @@ from .core.executor import TracedProgram, execute as _execute
 from .core.graph import CostGraph, Placement
 from .core.partitioner import PardnnOptions, pardnn_partition
 from .core.tracing import trace_cost_graph
+from .obs.spans import traced as _traced
 
 PLAN_FORMAT = "repro-partition-plan"
 PLAN_SCHEMA_VERSION = 1
@@ -571,6 +572,7 @@ class PartitionPlan:
                 code=_E.RP104_DEVICE_MISMATCH)
         return devices
 
+    @_traced("plan/execute", "plan")
     def execute(self, *args, devices=None, device_map=None,
                 runtime: str | None = None, donate: bool = True,
                 mode: str | None = None, trace: str | None = None,

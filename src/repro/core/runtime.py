@@ -62,7 +62,7 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
-from ..obs.spans import get_tracer as _obs_tracer
+from ..obs import spans as _obs
 from .costmodel import DeviceModel
 from .errors import PlanValidationError
 from .executor import TracedProgram, validate_device_count
@@ -373,7 +373,7 @@ class CompiledRuntime:
 
     # ------------------------------------------------------------------
     def __call__(self, *args, **kwargs):
-        prog, sched = self.prog, self.schedule
+        prog = self.prog
         flat_args = jax.tree_util.tree_leaves((args, kwargs))
         if len(flat_args) != len(prog.input_nodes):
             raise ValueError(f"expected {len(prog.input_nodes)} leaves, "
@@ -382,11 +382,29 @@ class CompiledRuntime:
         # it forces the serialized mode for attributable timings
         sync = self.mode == "sync" or self.profile_segments
         window = 0.0 if sync else float(self.transfer_window_bytes)
-        # telemetry: one flag read per call; every emit below is guarded
-        # so disabled tracing costs nothing on the dispatch hot path
-        obs = _obs_tracer()
-        obs_on = obs.enabled
-        obs_call_t0 = obs.now_us() if obs_on else 0.0
+        # telemetry: the sinks are read once per call, and span
+        # arguments are built only when one of them is on
+        traced = _obs.active()
+        call_span = (_obs.span("runtime/call", "runtime",
+                               mode="sync" if sync else "async",
+                               segments=self.stats.num_segments)
+                     if traced else _obs.span("runtime/call"))
+        with call_span:
+            result = self._run(flat_args, sync, window, traced)
+            if traced:
+                st = self.stats
+                call_span.set_metadata(
+                    transfers=st.transfers,
+                    prefetched=st.prefetched_transfers,
+                    deferred=st.deferred_transfers)
+        return result
+
+    def _run(self, flat_args: list, sync: bool, window: float,
+             traced: bool):
+        """One call's dispatch loop and final wait (``__call__``)."""
+        prog, sched = self.prog, self.schedule
+        obs = _obs.get_tracer()
+        obs_on = obs.enabled        # instants: the in-memory sink only
         t_start = time.perf_counter()
         k = len(self.devices)
         live = np.zeros(k, dtype=np.float64)
@@ -416,10 +434,11 @@ class CompiledRuntime:
         for nid, cv in self._const_vals.items():
             node_vals[nid] = cv
             alloc(self._pe_of(nid), _nbytes(cv))
-        for nid, a in zip(prog.input_nodes, flat_args):
-            v = jax.device_put(a, self._dev_of(nid))
-            node_vals[nid] = v
-            alloc(self._pe_of(nid), _nbytes(v))
+        with _obs.span("runtime/inputs", "runtime"):
+            for nid, a in zip(prog.input_nodes, flat_args):
+                v = jax.device_put(a, self._dev_of(nid))
+                node_vals[nid] = v
+                alloc(self._pe_of(nid), _nbytes(v))
         resident = live.copy()
         for slot in self._root_slots:
             env[slot] = node_vals[slot[0]]
@@ -481,87 +500,86 @@ class CompiledRuntime:
         dispatch_s: list[float] = []
         retained: list[tuple[tuple, list]] = []
         for seg in sched.segments:
-            seg_t0 = obs.now_us() if obs_on else 0.0
-            dev = self.devices[seg.device]
-            transfer_pos = set(seg.transfer_inputs)
-            donate_set = self._donate_sets[seg.sid]
-            dying_copy_bytes = 0.0      # donated copies die inside exe
-            invals = []
-            xfer_vals: list[Any] = []   # this segment's incoming copies
-            for pos, slot in enumerate(seg.inputs):
-                v = env[slot]
-                if pos in transfer_pos \
-                        and self._dev_of(slot[0]) is not dev:
-                    # cross-pe reads on *aliased* devices are no-copy
-                    # no-ops — only real copies count as transfers
-                    key = (slot, seg.device)
-                    cached = xfer_cache.get(key)
-                    if cached is not None:
-                        v = cached
-                        if pos in donate_set:      # last reader here
-                            xfer_cache.pop(key)
-                            nb = float(_nbytes(v))
-                            dying_copy_bytes += nb
-                            inflight -= nb
-                    else:
-                        # lazy issue: sync mode, window-deferred, or the
-                        # copy was already donated by an earlier reader
-                        nb = float(_nbytes(v))
-                        v = jax.device_put(v, dev)
-                        count_transfer(nb)
-                        alloc(seg.device, nb)
-                        if pos in donate_set:
-                            dying_copy_bytes += nb
+            dispatch_span = (_obs.span("runtime/dispatch", "runtime",
+                                       segment=seg.sid,
+                                       device=seg.device)
+                             if traced else _obs.span("runtime/dispatch"))
+            with dispatch_span:
+                dev = self.devices[seg.device]
+                transfer_pos = set(seg.transfer_inputs)
+                donate_set = self._donate_sets[seg.sid]
+                dying_copy_bytes = 0.0  # donated copies die inside exe
+                invals = []
+                xfer_vals: list[Any] = []   # the segment's incoming copies
+                for pos, slot in enumerate(seg.inputs):
+                    v = env[slot]
+                    if pos in transfer_pos \
+                            and self._dev_of(slot[0]) is not dev:
+                        # cross-pe reads on *aliased* devices are no-copy
+                        # no-ops — only real copies count as transfers
+                        key = (slot, seg.device)
+                        cached = xfer_cache.get(key)
+                        if cached is not None:
+                            v = cached
+                            if pos in donate_set:      # last reader here
+                                xfer_cache.pop(key)
+                                nb = float(_nbytes(v))
+                                dying_copy_bytes += nb
+                                inflight -= nb
                         else:
-                            inflight += nb
-                            if inflight > st.peak_inflight_transfer_bytes:
-                                st.peak_inflight_transfer_bytes = inflight
-                            xfer_cache[key] = v
-                            cache_by_src.setdefault(slot[0],
-                                                    []).append(key)
-                    if self._timeline \
-                            and key not in self._donated_copy_keys:
-                        xfer_vals.append(v)
-                invals.append(v)
-            exe = self._compiled.get(seg.sid)
-            if exe is None:
-                if obs_on:
-                    compile_t0 = obs.now_us()
-                t0 = time.perf_counter()
+                            # lazy issue: sync mode, window-deferred, or
+                            # the copy was already donated by an earlier
+                            # reader
+                            nb = float(_nbytes(v))
+                            v = jax.device_put(v, dev)
+                            count_transfer(nb)
+                            alloc(seg.device, nb)
+                            if pos in donate_set:
+                                dying_copy_bytes += nb
+                            else:
+                                inflight += nb
+                                if inflight > st.peak_inflight_transfer_bytes:
+                                    st.peak_inflight_transfer_bytes = inflight
+                                xfer_cache[key] = v
+                                cache_by_src.setdefault(slot[0],
+                                                        []).append(key)
+                        if self._timeline \
+                                and key not in self._donated_copy_keys:
+                            xfer_vals.append(v)
+                    invals.append(v)
+                exe = self._compiled.get(seg.sid)
+                if exe is None:
+                    t0 = time.perf_counter()
+                    with _obs.span("runtime/compile", "runtime",
+                                   segment=seg.sid), \
+                            warnings.catch_warnings():
+                        # CPU backends may decline donation; that is a
+                        # performance hint, not an error
+                        warnings.filterwarnings(
+                            "ignore", message=".*donated.*",
+                            category=UserWarning)
+                        exe = self._jits[seg.sid].lower(
+                            *invals).compile()
+                    compile_s += time.perf_counter() - t0
+                    self._compiled[seg.sid] = exe
+                t_seg = (time.perf_counter() if self.profile_segments
+                         else 0.0)
                 with warnings.catch_warnings():
-                    # CPU backends may decline donation; that is a
-                    # performance hint, not an error
                     warnings.filterwarnings(
                         "ignore", message=".*donated.*",
                         category=UserWarning)
-                    exe = self._jits[seg.sid].lower(*invals).compile()
-                compile_s += time.perf_counter() - t0
-                self._compiled[seg.sid] = exe
-                if obs_on:
-                    obs.complete(f"runtime/compile/seg{seg.sid}",
-                                 compile_t0, obs.now_us() - compile_t0,
-                                 "runtime", {"segment": seg.sid,
-                                             "device": seg.device})
-            t_seg = time.perf_counter() if self.profile_segments else 0.0
-            with warnings.catch_warnings():
-                warnings.filterwarnings("ignore", message=".*donated.*",
-                                        category=UserWarning)
-                outs = exe(*invals)
-            if self.profile_segments:
-                jax.block_until_ready(outs)
-                seg_seconds.append(time.perf_counter() - t_seg)
-            elif sync:
-                jax.block_until_ready(outs)
-            if not invals:
-                # no committed inputs to infer placement from: pin the
-                # outputs to the segment's device explicitly
-                outs = tuple(jax.device_put(o, dev) for o in outs)
-            dispatch_s.append(time.perf_counter() - t_start - compile_s)
-            if obs_on:
-                obs.complete(f"runtime/dispatch/seg{seg.sid}", seg_t0,
-                             obs.now_us() - seg_t0, "runtime",
-                             {"segment": seg.sid, "device": seg.device,
-                              "nodes": len(seg.nodes)})
+                    outs = exe(*invals)
+                if self.profile_segments:
+                    jax.block_until_ready(outs)
+                    seg_seconds.append(time.perf_counter() - t_seg)
+                elif sync:
+                    jax.block_until_ready(outs)
+                if not invals:
+                    # no committed inputs to infer placement from: pin
+                    # the outputs to the segment's device explicitly
+                    outs = tuple(jax.device_put(o, dev) for o in outs)
+                dispatch_s.append(time.perf_counter() - t_start
+                                  - compile_s)
             for slot, v in zip(seg.outputs, outs):
                 env[slot] = v
                 alloc(seg.device, _nbytes(v))
@@ -604,23 +622,25 @@ class CompiledRuntime:
         ready_s: list[float] = []
         done_s: list[float] = []
         xfer_wait_s: list[float] = []
-        if self._timeline:
-            # observed-completion sweep: runs while execution is still
-            # in flight (dispatch above never blocked), so each block
-            # returns at ~the segment's true completion for segments
-            # finishing in dispatch order
-            for seg_outs, seg_xfers in retained:
-                t0 = time.perf_counter()
-                if seg_xfers:
-                    jax.block_until_ready(seg_xfers)
-                t1 = time.perf_counter()
-                ready_s.append(t1 - t_start - compile_s)
-                xfer_wait_s.append(t1 - t0)
-                jax.block_until_ready(seg_outs)
-                done_s.append(time.perf_counter() - t_start - compile_s)
-        # sync before reading the clock: under async dispatch the wall
-        # time up to here is dispatch time, not execution time
-        jax.block_until_ready([o for o in outs if o is not None])
+        with _obs.span("runtime/wait", "runtime"):
+            if self._timeline:
+                # observed-completion sweep: runs while execution is
+                # still in flight (dispatch above never blocked), so each
+                # block returns at ~the segment's true completion for
+                # segments finishing in dispatch order
+                for seg_outs, seg_xfers in retained:
+                    t0 = time.perf_counter()
+                    if seg_xfers:
+                        jax.block_until_ready(seg_xfers)
+                    t1 = time.perf_counter()
+                    ready_s.append(t1 - t_start - compile_s)
+                    xfer_wait_s.append(t1 - t0)
+                    jax.block_until_ready(seg_outs)
+                    done_s.append(time.perf_counter() - t_start
+                                  - compile_s)
+            # sync before reading the clock: under async dispatch the
+            # wall time up to here is dispatch time, not execution time
+            jax.block_until_ready([o for o in outs if o is not None])
         self.stats.compile_seconds += compile_s
         self.stats.execute_seconds = (time.perf_counter() - t_start
                                       - compile_s)
@@ -633,13 +653,6 @@ class CompiledRuntime:
         self.stats.transfer_wait_seconds = xfer_wait_s
         self.stats.peak_live_bytes = [float(x) for x in peak]
         self.stats.resident_bytes = [float(x) for x in resident]
-        if obs_on:
-            obs.complete("runtime/call", obs_call_t0,
-                         obs.now_us() - obs_call_t0, "runtime",
-                         {"mode": st.mode, "segments": st.num_segments,
-                          "transfers": st.transfers,
-                          "prefetched": st.prefetched_transfers,
-                          "deferred": st.deferred_transfers})
         return result
 
 

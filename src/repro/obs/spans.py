@@ -6,16 +6,34 @@ stack: partitioner stages, the compiled runtime's dispatch loop, the
 serving engine's request lifecycle. The buffer is drained into a
 Perfetto-loadable JSON document by ``repro.obs.trace``.
 
+Two sinks
+---------
+A span goes to each of two sinks that is on:
+
+* the in-memory tracer above (:func:`enable` / ``REPRO_TRACE``);
+* the JAX profiler: while a profiler session is active
+  (``jax.profiler.start_trace``), every span is also a
+  ``jax.profiler.TraceAnnotation`` named ``repro/<span name>``, with the
+  span's arguments as its metadata, on the device trace's clock. There
+  is no flag: a profiled run gets the program's spans.
+
+This module imports no JAX. The profiler probe
+(``TraceAnnotation.is_enabled``) is looked up once something else has
+imported JAX; before that no session can be active. Instants and
+counters go to the in-memory tracer only.
+
 Overhead policy
 ---------------
 Tracing is **off by default** and the disabled path is engineered to be
 invisible in hot loops:
 
-* ``span(name)`` with no kwargs performs one attribute load and one
-  branch, then returns a shared immutable no-op singleton — **zero
-  allocations** (pinned by ``tests/test_obs.py`` with ``tracemalloc``).
-* Call sites that build event arguments guard on :func:`enabled` first,
-  so argument dicts are never constructed when tracing is off.
+* ``span(name)`` with no kwargs performs one attribute load, one branch
+  and the profiler probe (about 45 ns once JAX is imported), then
+  returns a shared immutable no-op singleton — **zero allocations**,
+  with or without JAX imported (pinned by ``tests/test_obs.py`` with
+  ``tracemalloc``).
+* Call sites that build event arguments guard on :func:`active` first,
+  so argument dicts are never constructed when both sinks are off.
 * The acceptance budget is <2% wall overhead on
   ``benchmarks/bench_overhead.py --runtime`` with tracing disabled.
 
@@ -37,6 +55,7 @@ from __future__ import annotations
 import atexit
 import functools
 import os
+import sys
 import threading
 import time
 from typing import Any
@@ -50,6 +69,9 @@ PH_METADATA = "M"
 #: pid of the live in-process lanes (host threads). The trace exporter
 #: reserves further pids for measured / predicted device lanes.
 HOST_PID = 0
+
+#: name prefix of the spans written into the JAX profiler's trace
+PROFILER_PREFIX = "repro/"
 
 
 class Tracer:
@@ -113,19 +135,29 @@ class Tracer:
 
 
 class _Span:
-    """Live span: records a complete ("X") event on exit. Nesting is
+    """Live span: records a complete ("X") event on exit, and holds the
+    profiler's annotation while a profiler session is active. Nesting is
     correct by construction — Perfetto stacks same-thread X events by
     their [ts, ts+dur] containment, and a with-block exits LIFO."""
-    __slots__ = ("_tracer", "name", "cat", "args", "_start")
+    __slots__ = ("_tracer", "name", "cat", "args", "_start", "_ann")
 
     def __init__(self, tracer: Tracer, name: str, cat: str,
-                 args: dict | None) -> None:
+                 args: dict | None, ann: Any = None) -> None:
         self._tracer = tracer
         self.name = name
         self.cat = cat
         self.args = args
+        self._ann = ann
+
+    def set_metadata(self, **args: Any) -> None:
+        """Add arguments known only once the span's work is done."""
+        self.args = {**(self.args or {}), **args}
+        if self._ann is not None:
+            self._ann.set_metadata(**args)
 
     def __enter__(self) -> "_Span":
+        if self._ann is not None:
+            self._ann.__enter__()
         self._start = self._tracer.now_us()
         return self
 
@@ -133,12 +165,17 @@ class _Span:
         t = self._tracer
         t.complete(self.name, self._start, t.now_us() - self._start,
                    self.cat, self.args)
+        if self._ann is not None:
+            self._ann.__exit__(*exc)
 
 
 class _NullSpan:
     """Shared no-op span for the disabled path — a singleton so the
     disabled ``span()`` call allocates nothing."""
     __slots__ = ()
+
+    def set_metadata(self, **args: Any) -> None:
+        pass
 
     def __enter__(self) -> "_NullSpan":
         return self
@@ -149,6 +186,23 @@ class _NullSpan:
 
 _NULL_SPAN = _NullSpan()
 _TRACER = Tracer()
+# jax.profiler.TraceAnnotation, once JAX has been imported
+_ANNOTATION: Any = None
+
+
+def _profiling() -> bool:
+    """Whether a JAX profiler session is active. Until JAX is imported
+    there can be none; after, the probe is ``TraceAnnotation.is_enabled``
+    (one flag read in C++)."""
+    global _ANNOTATION
+    ann = _ANNOTATION
+    if ann is None:
+        ann = getattr(sys.modules.get("jax._src.profiler"),
+                      "TraceAnnotation", None)
+        if ann is None:
+            return False
+        _ANNOTATION = ann
+    return ann.is_enabled()
 
 
 def get_tracer() -> Tracer:
@@ -156,7 +210,14 @@ def get_tracer() -> Tracer:
 
 
 def enabled() -> bool:
+    """Whether the in-memory tracer records."""
     return _TRACER.enabled
+
+
+def active() -> bool:
+    """Whether either sink takes spans: call sites guard building span
+    arguments on it."""
+    return _TRACER.enabled or _profiling()
 
 
 def enable(on: bool = True) -> None:
@@ -164,25 +225,29 @@ def enable(on: bool = True) -> None:
 
 
 def span(name: str, cat: str = "repro", **args: Any):
-    """Context manager timing a named region. Disabled: returns the
-    shared no-op singleton (zero allocation when called without kwargs).
-    """
+    """Context manager timing a named region into each sink that is on.
+    Both off: returns the shared no-op singleton (zero allocation when
+    called without kwargs). ``set_metadata(**args)`` on the returned
+    span adds arguments known only at its end."""
     t = _TRACER
-    if not t.enabled:
-        return _NULL_SPAN
-    return _Span(t, name, cat, args or None)
+    if t.enabled:
+        ann = (_ANNOTATION(PROFILER_PREFIX + name, **args)
+               if _profiling() else None)
+        return _Span(t, name, cat, args or None, ann)
+    if _profiling():
+        return _ANNOTATION(PROFILER_PREFIX + name, **args)
+    return _NULL_SPAN
 
 
 def traced(name: str, cat: str = "repro"):
     """Decorator form of :func:`span` — wraps a whole function body.
-    Disabled tracing costs one extra call frame and a branch."""
+    Both sinks off costs one extra call frame and two branches."""
     def deco(fn):
         @functools.wraps(fn)
         def wrapper(*a, **kw):
-            t = _TRACER
-            if not t.enabled:
+            if not (_TRACER.enabled or _profiling()):
                 return fn(*a, **kw)
-            with _Span(t, name, cat, None):
+            with span(name, cat):
                 return fn(*a, **kw)
         return wrapper
     return deco
@@ -224,6 +289,6 @@ if _env and _env.lower() not in ("0", "false"):
     atexit.register(_atexit_export)
 
 
-__all__ = ["Tracer", "get_tracer", "enabled", "enable", "span",
-           "instant", "counter", "HOST_PID", "PH_COMPLETE", "PH_INSTANT",
-           "PH_COUNTER", "PH_METADATA"]
+__all__ = ["Tracer", "get_tracer", "enabled", "active", "enable", "span",
+           "traced", "instant", "counter", "HOST_PID", "PROFILER_PREFIX",
+           "PH_COMPLETE", "PH_INSTANT", "PH_COUNTER", "PH_METADATA"]

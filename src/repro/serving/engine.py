@@ -40,6 +40,7 @@ import numpy as np
 from repro import obs as _obs
 from repro.configs.base import ModelConfig
 from repro.models import decode_step, prefill_batched
+from repro.obs.spans import PH_COMPLETE, PH_COUNTER, PH_INSTANT
 from repro.obs.stats import latency_summary
 
 from . import kvcache
@@ -135,7 +136,9 @@ class ServingEngine:
             request lifecycle (queued+prefill / decode lanes per
             request, eviction markers), admission batches, decode
             steps, and block-pool occupancy counters
-            (``repro.obs.trace``; open in ui.perfetto.dev).
+            (``repro.obs.trace``; open in ui.perfetto.dev). The engine
+            records them through ``repro.obs``, whose in-memory sink it
+            turns on from construction to drain.
     """
 
     def __init__(self, cfg: ModelConfig, params, *, block_size: int = 16,
@@ -172,13 +175,14 @@ class ServingEngine:
         self.pools = kvcache.init_pools(cfg, num_blocks, self.block_size)
         self.stats = ServingStats()
         self.completed: dict[int, ServingRequest] = {}
-        # engine-local trace recording (independent of the global obs
-        # tracer): (kind, ts_s, dur_s, args) rows, exported at drain
+        # trace=: the engine's spans, counters and evictions go to the
+        # repro.obs tracer, on from here to drain; whether it was on
+        # before decides what write_trace keeps of its buffer
         self._trace_path = trace
-        self._trace_t0 = time.perf_counter()
-        self._trace_events: list[tuple] = []
+        self._trace_t0 = _obs.get_tracer().now_us()
+        self._obs_was_on = _obs.enabled()
         if trace is not None:
-            self.scheduler.on_evict = self._record_evict
+            _obs.enable()
         self.plan = plan
         self._devices = devices
         self._device_map = device_map
@@ -258,7 +262,8 @@ class ServingEngine:
                 f"engine's max_len ({self.max_len}) — the KV cache would "
                 f"overflow; shorten the prompt, lower max_new_tokens, or "
                 f"raise max_len")
-        req.arrival_s = time.perf_counter()
+        if req.arrival_s is None:       # else the caller's arrival time
+            req.arrival_s = time.perf_counter()
         self.stats.submitted += 1
         _obs.instant("serving/submit", "serving", rid=req.rid,
                      prompt_tokens=plen)
@@ -269,26 +274,32 @@ class ServingEngine:
         """One padded prefill for every admission: a single device call
         and a single host sync for the whole batch (no per-admit
         ``int(argmax)`` round-trips)."""
-        B = _ceil_pow2(len(admits))
-        S = _ceil_pow2(max(len(a.prompt) for a in admits), floor=8)
-        tokens = np.zeros((B, S), dtype=np.int32)
-        plens = np.ones((B,), dtype=np.int32)
-        for j, a in enumerate(admits):
-            tokens[j, :len(a.prompt)] = a.prompt
-            plens[j] = len(a.prompt)
-        logits, caches = self._prefill_fn(B, S)(
-            self.params, jnp.asarray(tokens), jnp.asarray(plens))
-        nxt = np.asarray(jnp.argmax(logits[:, -1], axis=-1),
-                         dtype=np.int32)                  # one host sync
+        with _obs.span("serving/prefill_forward", "serving"):
+            B = _ceil_pow2(len(admits))
+            S = _ceil_pow2(max(len(a.prompt) for a in admits), floor=8)
+            tokens = np.zeros((B, S), dtype=np.int32)
+            plens = np.ones((B,), dtype=np.int32)
+            for j, a in enumerate(admits):
+                tokens[j, :len(a.prompt)] = a.prompt
+                plens[j] = len(a.prompt)
+            logits, caches = self._prefill_fn(B, S)(
+                self.params, jnp.asarray(tokens), jnp.asarray(plens))
+        with _obs.span("serving/sample", "serving"):
+            nxt = np.asarray(jnp.argmax(logits[:, -1], axis=-1),
+                             dtype=np.int32)              # one host sync
         now = time.perf_counter()
         self.stats.prefill_calls += 1
         self.stats.prefill_tokens += int(sum(len(a.prompt) for a in admits))
         self.stats.admitted += len(admits)
+        traced = _obs.active()
         for j, a in enumerate(admits):
             req = a.req
-            self.pools = kvcache.write_prompt(
-                self.pools, req.blocks, caches, j, len(a.prompt),
-                self.block_size)
+            with (_obs.span("serving/write_prompt", "serving",
+                            blocks=len(req.blocks), tokens=len(a.prompt))
+                  if traced else _obs.span("serving/write_prompt")):
+                self.pools = kvcache.write_prompt(
+                    self.pools, req.blocks, caches, j, len(a.prompt),
+                    self.block_size)
             req.emit(int(nxt[j]), now)
             self.stats.generated_tokens += 1
             req.state = RequestState.DECODE
@@ -306,29 +317,30 @@ class ServingEngine:
         DECODE-state request (rows beyond the active set are padding
         aimed at the null block)."""
         sched = self.scheduler
-        batch = []
-        for req in sorted(sched.decoding(), key=lambda r: r.admit_seq):
-            if req.state != RequestState.DECODE:
-                continue        # evicted by an earlier ensure_block
-            if sched.ensure_block(req):
-                batch.append(req)
-        # ensure_block may have evicted members picked earlier
-        batch = [r for r in batch if r.state == RequestState.DECODE]
-        if not batch:
-            return 0
-        B, W = self.max_batch, self.max_blocks_per_req
-        bt = np.zeros((B, W), dtype=np.int32)
-        toks = np.zeros((B, 1), dtype=np.int32)
-        lens = np.zeros((B,), dtype=np.int32)
-        for i, req in enumerate(batch):
-            bt[i, :len(req.blocks)] = req.blocks
-            toks[i, 0] = req.output[-1]
-            lens[i] = req.length
-        logits, self.pools = self._decode(
-            self.params, self.pools, jnp.asarray(bt), jnp.asarray(toks),
-            jnp.asarray(lens))
-        nxt = np.asarray(jnp.argmax(logits[:, -1], axis=-1),
-                         dtype=np.int32)                  # one host sync
+        with _obs.span("serving/decode_inputs", "serving"):
+            batch = []
+            for req in sorted(sched.decoding(), key=lambda r: r.admit_seq):
+                if req.state != RequestState.DECODE:
+                    continue        # evicted by an earlier ensure_block
+                if sched.ensure_block(req):
+                    batch.append(req)
+            # ensure_block may have evicted members picked earlier
+            batch = [r for r in batch if r.state == RequestState.DECODE]
+            if not batch:
+                return 0
+            B, W = self.max_batch, self.max_blocks_per_req
+            bt = np.zeros((B, W), dtype=np.int32)
+            toks = np.zeros((B, 1), dtype=np.int32)
+            lens = np.zeros((B,), dtype=np.int32)
+            for i, req in enumerate(batch):
+                bt[i, :len(req.blocks)] = req.blocks
+                toks[i, 0] = req.output[-1]
+                lens[i] = req.length
+            inputs = (jnp.asarray(bt), jnp.asarray(toks), jnp.asarray(lens))
+        logits, self.pools = self._decode(self.params, self.pools, *inputs)
+        with _obs.span("serving/sample", "serving"):
+            nxt = np.asarray(jnp.argmax(logits[:, -1], axis=-1),
+                             dtype=np.int32)              # one host sync
         now = time.perf_counter()
         self.stats.decode_steps += 1
         for i, req in enumerate(batch):
@@ -339,45 +351,33 @@ class ServingEngine:
                 self._finish(req)
         return len(batch)
 
-    def _record_evict(self, req: ServingRequest) -> None:
-        self._trace_events.append(
-            ("evict", time.perf_counter(), 0.0,
-             {"rid": req.rid, "evictions": req.evictions,
-              "generated": len(req.output)}))
-
     def tick(self) -> int:
         """One engine step: admit+prefill, then decode every active
         request by one token. Returns the number of requests advanced."""
         self.stats.ticks += 1
-        rec = self._trace_path is not None
-        admits = self.scheduler.schedule_admissions()
-        if admits:
-            t0 = time.perf_counter() if rec else 0.0
-            with _obs.span("serving/prefill_batch"):
-                self._run_prefill(admits)
-            if rec:
-                self._trace_events.append(
-                    ("prefill_batch", t0, time.perf_counter() - t0,
-                     {"admitted": len(admits),
-                      "tokens": int(sum(len(a.prompt) for a in admits)),
-                      "rids": [a.req.rid for a in admits]}))
-        t1 = time.perf_counter() if rec else 0.0
-        with _obs.span("serving/decode_step"):
-            decoded = self._run_decode()
-        if rec and decoded:
-            self._trace_events.append(
-                ("decode_step", t1, time.perf_counter() - t1,
-                 {"batch": decoded}))
+        traced = _obs.active()
+        with _obs.span("serving/tick", "serving") as tick_span:
+            with _obs.span("serving/schedule", "serving"):
+                admits = self.scheduler.schedule_admissions()
+            if admits:
+                with (_obs.span(
+                        "serving/prefill_batch", "serving",
+                        admitted=len(admits),
+                        tokens=int(sum(len(a.prompt) for a in admits)),
+                        rids=[a.req.rid for a in admits])
+                      if traced else _obs.span("serving/prefill_batch")):
+                    self._run_prefill(admits)
+            with _obs.span("serving/decode_step", "serving") as dec_span:
+                decoded = self._run_decode()
+                if traced:
+                    dec_span.set_metadata(batch=decoded)
+            if traced:
+                tick_span.set_metadata(admitted=len(admits),
+                                       decoded=decoded)
         advanced = decoded + len(admits)
         self.stats.peak_active = max(self.stats.peak_active,
                                      len(self.scheduler.active))
         self.stats.peak_blocks_in_use = self.allocator.peak_in_use
-        if rec:
-            self._trace_events.append(
-                ("counter", time.perf_counter(), 0.0,
-                 {"blocks_in_use": self.allocator.num_in_use,
-                  "active": len(self.scheduler.active),
-                  "waiting": len(self.scheduler.waiting)}))
         if _obs.enabled():
             _obs.counter("serving/pool", "serving",
                          blocks_in_use=self.allocator.num_in_use,
@@ -403,30 +403,39 @@ class ServingEngine:
         return self.completed
 
     def write_trace(self, path: str) -> str:
-        """Export the recorded serving trace: one engine lane
-        (admission batches, decode steps, pool-occupancy counters) plus
-        one lane per completed request (queued+prefill span from
-        arrival to first token, decode span to the last token, eviction
-        markers)."""
+        """Export the serving trace from the ``repro.obs`` buffer: one
+        engine lane (admission batches, decode steps, pool-occupancy
+        counters) plus one lane per completed request (queued+prefill
+        span from arrival to first token, decode span to the last token,
+        eviction markers). When the tracer was on before the engine, its
+        whole buffer also goes to the host lanes (pid 0), as
+        :func:`repro.obs.export_spans` would write it; else the engine
+        turns it off again and drops what it recorded."""
         from repro.obs.trace import SERVING_PID, TraceBuilder
+        tracer = _obs.get_tracer()
         b = TraceBuilder()
         b.process(SERVING_PID, "serving")
         b.thread(SERVING_PID, 0, "engine")
-        t0 = self._trace_t0
+        epoch = tracer.epoch()
 
         def us(t: float) -> float:
-            return (t - t0) * 1e6
+            return (t - epoch) * 1e6
 
-        for kind, ts, dur, args in self._trace_events:
-            if kind == "counter":
-                b.counter(SERVING_PID, 0, "pool", us(ts), args,
-                          cat="serving")
-            elif kind == "evict":
+        for ph, name, _cat, _pid, _tid, ts, dur, args in tracer.events:
+            if ts < self._trace_t0:
+                continue
+            if ph == PH_COMPLETE and name in ("serving/prefill_batch",
+                                              "serving/decode_step"):
+                if name == "serving/decode_step" \
+                        and not (args or {}).get("batch"):
+                    continue        # a tick with nothing to decode
+                b.complete(SERVING_PID, 0, name.removeprefix("serving/"),
+                           ts, dur, cat="serving", args=args)
+            elif ph == PH_COUNTER and name == "serving/pool":
+                b.counter(SERVING_PID, 0, "pool", ts, args, cat="serving")
+            elif ph == PH_INSTANT and name == "serving/evict":
                 b.instant(SERVING_PID, 1 + int(args["rid"]), "evicted",
-                          us(ts), cat="serving", args=args)
-            else:
-                b.complete(SERVING_PID, 0, kind, us(ts), dur * 1e6,
-                           cat="serving", args=args)
+                          ts, cat="serving", args=args)
         for rid, req in sorted(self.completed.items()):
             tid = 1 + rid
             b.thread(SERVING_PID, tid, f"request {rid}")
@@ -445,8 +454,11 @@ class ServingEngine:
                            cat="serving",
                            args={"rid": rid, "tokens": len(req.output),
                                  "evictions": req.evictions})
-        if _obs.enabled():
-            b.add_spans()
+        if self._obs_was_on:
+            b.add_spans(tracer)
+        else:
+            _obs.enable(False)
+            tracer.clear()
         return b.save(path)
 
 

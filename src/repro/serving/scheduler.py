@@ -66,8 +66,9 @@ class ServingRequest:
     admissions: int = 0                      # prefill passes (1 + resumes)
     evictions: int = 0
 
-    # latency timestamps (perf_counter seconds)
-    arrival_s: float = 0.0
+    # latency timestamps (perf_counter seconds); arrival_s is stamped at
+    # submit() unless the caller set it (e.g. to the request's due time)
+    arrival_s: float | None = None
     first_token_s: float | None = None
     token_times: list = field(default_factory=list)
 
@@ -139,9 +140,6 @@ class Scheduler:
         self.waiting: deque[ServingRequest] = deque()
         self.active: list[ServingRequest] = []   # PREFILL/DECODE
         self._admit_counter = itertools.count()
-        # optional eviction observer (the engine's trace recorder);
-        # called with the victim right after it is re-queued
-        self.on_evict: Callable[[ServingRequest], None] | None = None
 
     # -- queue ----------------------------------------------------------
     def submit(self, req: ServingRequest) -> None:
@@ -227,8 +225,6 @@ class Scheduler:
         _obs_instant("serving/evict", "serving", rid=victim.rid,
                      evictions=victim.evictions,
                      generated=len(victim.output))
-        if self.on_evict is not None:
-            self.on_evict(victim)
         return victim
 
     # -- completion ------------------------------------------------------

@@ -99,14 +99,19 @@ def test_disabled_span_is_the_shared_null_singleton():
     assert spans.get_tracer().events == []
 
 
-def test_disabled_span_allocates_nothing():
+@pytest.mark.parametrize("jax_imported", [False, True],
+                         ids=["jax-not-imported", "jax-imported-no-profiler"])
+def test_disabled_span_allocates_nothing(jax_imported):
     # measured in a fresh interpreter: inside the suite, jax worker
     # threads allocate concurrently and make tracemalloc numbers
-    # order-dependent; a bare process pins the claim deterministically
+    # order-dependent; a bare process pins the claim deterministically.
+    # With JAX imported the profiler probe runs on every span.
     code = (
-        "import tracemalloc\n"
+        "import sys, tracemalloc\n"
         "from repro.obs import spans\n"
-        "def hot(n):\n"
+        "assert 'jax' not in sys.modules, 'repro.obs imported jax'\n"
+        + ("import jax\n" if jax_imported else "")
+        + "def hot(n):\n"
         "    for _ in range(n):\n"
         "        with spans.span('hot'):\n"
         "            pass\n"
@@ -116,12 +121,76 @@ def test_disabled_span_allocates_nothing():
         "current, _peak = tracemalloc.get_traced_memory()\n"
         "assert current == 0, f'{current} bytes leaked'\n"
         "print('ZERO_ALLOC_OK')\n")
-    env = dict(os.environ, PYTHONPATH=repo_src_path())
+    env = dict(os.environ, PYTHONPATH=repo_src_path(), JAX_PLATFORMS="cpu")
     env.pop("REPRO_TRACE", None)
     r = subprocess.run([sys.executable, "-c", code], env=env,
                        capture_output=True, text=True, timeout=120)
     assert r.returncode == 0, r.stderr
     assert "ZERO_ALLOC_OK" in r.stdout
+
+
+_PROFILER_SNIPPET = """
+import glob, json, os, sys
+import jax
+from jax.profiler import ProfileData
+from repro.obs import spans
+
+def names_in(d):
+    (path,) = glob.glob(os.path.join(d, "**", "*.xplane.pb"),
+                        recursive=True)
+    out = []
+    for plane in ProfileData.from_file(path).planes:
+        if plane.name.startswith("/host:"):
+            for line in plane.lines:
+                out += [(e.name, e.start_ns, e.end_ns,
+                         dict(e.stats)) for e in line.events
+                        if e.name.startswith("repro/")]
+    return out
+
+on, off = sys.argv[1], sys.argv[2]
+with spans.span("before"):
+    pass
+jax.profiler.start_trace(on)
+with spans.span("outer", step=3):
+    with spans.span("inner"):
+        jax.numpy.ones(4).block_until_ready()
+    with spans.span("late") as s:
+        s.set_metadata(done=1)
+jax.profiler.stop_trace()
+with spans.span("after"):
+    pass
+null_after_stop = spans.span("x") is spans._NULL_SPAN
+jax.profiler.start_trace(off)
+jax.profiler.stop_trace()
+print("PROF:" + json.dumps({"on": names_in(on), "off": names_in(off),
+                            "null_after_stop": null_after_stop,
+                            "buffered": len(spans.get_tracer().events)}))
+"""
+
+
+def test_spans_reach_the_jax_profiler(tmp_path):
+    """With a profiler session active and the in-memory tracer off, the
+    spans are profiler events named ``repro/<name>``, nested as they
+    were opened, carrying their arguments; outside it they are not."""
+    env = dict(os.environ, PYTHONPATH=repo_src_path(), JAX_PLATFORMS="cpu")
+    env.pop("REPRO_TRACE", None)
+    r = subprocess.run([sys.executable, "-c", _PROFILER_SNIPPET,
+                        str(tmp_path / "on"), str(tmp_path / "off")],
+                       env=env, capture_output=True, text=True, timeout=300)
+    assert r.returncode == 0, r.stderr[-4000:]
+    out = json.loads(r.stdout.splitlines()[-1].removeprefix("PROF:"))
+    ev = {name.split("#")[0]: (a, b, stats)
+          for name, a, b, stats in out["on"]}
+    assert sorted(ev) == ["repro/inner", "repro/late", "repro/outer"]
+    o0, o1, ostats = ev["repro/outer"]
+    for child in ("repro/inner", "repro/late"):
+        assert o0 <= ev[child][0] <= ev[child][1] <= o1
+    assert ev["repro/inner"][1] <= ev["repro/late"][0]
+    assert ostats == {"step": 3}
+    assert ev["repro/late"][2] == {"done": 1}
+    assert out["off"] == []
+    assert out["null_after_stop"]
+    assert out["buffered"] == 0     # the in-memory sink stayed off
 
 
 def test_disabled_instant_and_counter_record_nothing():
@@ -449,6 +518,95 @@ def test_plan_trace_merges_predicted_and_measured_lanes(tmp_path):
     assert payload["all_positive"]
     assert payload["drift"] <= 1e-4
     assert payload["runtime_recorded"]
+
+
+def _by_name(events):
+    out = {}
+    for ph, name, _cat, _pid, _tid, ts, dur, args in events:
+        if ph == spans.PH_COMPLETE:
+            out.setdefault(name, []).append((ts, ts + dur, args or {}))
+    return out
+
+
+def test_runtime_spans_nest_in_plan_execute():
+    """The compiled runtime's live spans: one ``plan/execute`` per call
+    holding ``runtime/call``, ``runtime/inputs``, one ``runtime/dispatch``
+    per segment (with its segment and device), ``runtime/compile`` on
+    the first call only, and ``runtime/wait``."""
+    import jax
+    import jax.numpy as jnp
+
+    import repro
+
+    def f(w, x):
+        return jnp.sum(jnp.tanh(x @ w))
+
+    key = jax.random.PRNGKey(0)
+    w = jax.random.normal(key, (4, 4))
+    x = jax.random.normal(key, (2, 4))
+    plan = repro.partition(repro.trace(f, w, x, record=True), devices=1)
+    spans.enable()
+    plan.execute(w, x)
+    first = _by_name(spans.get_tracer().drain())
+    plan.execute(w, x)
+    second = _by_name(spans.get_tracer().drain())
+    nseg = plan.report.runtime["num_segments"]
+    assert len(first["runtime/compile"]) == nseg
+    assert "runtime/compile" not in second
+    for ev in (first, second):
+        (outer,) = ev["plan/execute"]
+        for name in ("runtime/call", "runtime/inputs", "runtime/wait"):
+            (inner,) = ev[name]
+            assert outer[0] <= inner[0] <= inner[1] <= outer[1]
+        assert len(ev["runtime/dispatch"]) == nseg
+        assert {a["segment"] for _, _, a in ev["runtime/dispatch"]} == \
+            set(range(nseg))
+        assert all("device" in a for _, _, a in ev["runtime/dispatch"])
+        (call,) = ev["runtime/call"]
+        assert {"mode", "segments", "transfers"} <= set(call[2])
+
+
+def test_serving_engine_spans():
+    """A tick's spans: schedule, prefill_batch holding prefill_forward,
+    sample and one write_prompt per admitted request (blocks, tokens),
+    decode_step holding decode_inputs and sample; the tick's own span
+    carries what it admitted and decoded."""
+    import jax
+    import numpy as np
+
+    from repro.configs import get_config, reduced
+    from repro.models import init_params
+    from repro.serving import Request, ServingEngine
+
+    cfg = reduced(get_config("granite-8b"))
+    params = init_params(cfg, jax.random.PRNGKey(0))
+    eng = ServingEngine(cfg, params, block_size=8, num_blocks=32,
+                        max_batch=2, max_len=64)
+    for rid, n in enumerate((5, 11)):
+        eng.submit(Request(rid=rid, prompt=np.arange(1, n + 1,
+                                                     dtype=np.int32),
+                           max_new_tokens=3))
+    spans.enable()
+    eng.tick()
+    ev = _by_name(spans.get_tracer().drain())
+    (tick,) = ev["serving/tick"]
+    assert tick[2] == {"admitted": 2, "decoded": 2}
+    for name in ("serving/schedule", "serving/prefill_batch",
+                 "serving/decode_step"):
+        (inner,) = ev[name]
+        assert tick[0] <= inner[0] <= inner[1] <= tick[1]
+    (prefill,) = ev["serving/prefill_batch"]
+    (forward,) = ev["serving/prefill_forward"]
+    assert prefill[0] <= forward[0] <= forward[1] <= prefill[1]
+    writes = ev["serving/write_prompt"]
+    assert sorted((a["blocks"], a["tokens"]) for _, _, a in writes) == \
+        [(1, 5), (2, 11)]
+    assert all(prefill[0] <= a <= b <= prefill[1] for a, b, _ in writes)
+    (decode,) = ev["serving/decode_step"]
+    (inputs,) = ev["serving/decode_inputs"]
+    assert decode[0] <= inputs[0] <= inputs[1] <= decode[1]
+    assert decode[2] == {"batch": 2}
+    assert len(ev["serving/sample"]) == 2     # one sync each phase
 
 
 def test_serving_engine_trace_export(tmp_path):

@@ -321,6 +321,29 @@ def test_latency_metrics_recorded(setup):
         assert len(r.inter_token_s()) == len(r.output) - 1
 
 
+def test_ttft_counts_the_wait_before_submit(setup):
+    """A caller-set arrival time is kept, so TTFT counts the wait before
+    submit(); an unset one is stamped at submit()."""
+    import time
+    cfg, params = setup
+    eng = ServingEngine(cfg, params, block_size=4, num_blocks=32,
+                        max_batch=2, max_len=32, jit=False)
+    prompt = np.arange(1, 5, dtype=np.int32)
+    arrived = time.perf_counter() - 5.0
+    early = Request(rid=0, prompt=prompt, max_new_tokens=2)
+    early.arrival_s = arrived
+    late = Request(rid=1, prompt=prompt, max_new_tokens=2)
+    t_submit = time.perf_counter()
+    eng.submit(early)
+    eng.submit(late)
+    eng.run_until_drained()
+    assert early.arrival_s == arrived
+    assert late.arrival_s >= t_submit
+    assert early.ttft_s() >= 5.0
+    assert late.ttft_s() < early.ttft_s() - 4.0
+    assert max(eng.stats.ttft_s) == early.ttft_s()
+
+
 # ---------------------------------------------------------------------------
 # scheduler unit behavior (no model)
 # ---------------------------------------------------------------------------
