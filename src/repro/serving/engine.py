@@ -71,6 +71,8 @@ class ServingStats:
     ticks: int = 0
     prefill_calls: int = 0
     prefill_tokens: int = 0
+    prompt_writes: int = 0         # write_prompt's device calls
+    prompt_write_blocks: int = 0   # prompt blocks write_prompt wrote
     decode_steps: int = 0
     generated_tokens: int = 0
     peak_active: int = 0
@@ -99,6 +101,8 @@ class ServingStats:
             "completed": self.completed, "rejected": self.rejected,
             "ticks": self.ticks, "prefill_calls": self.prefill_calls,
             "prefill_tokens": self.prefill_tokens,
+            "prompt_writes": self.prompt_writes,
+            "prompt_write_blocks": self.prompt_write_blocks,
             "decode_steps": self.decode_steps,
             "generated_tokens": self.generated_tokens,
             "peak_active": self.peak_active,
@@ -195,6 +199,9 @@ class ServingEngine:
         else:
             self._decode = (jax.jit(self._decode_impl, donate_argnums=(1,))
                             if self._jit else self._decode_impl)
+        # write_prompt makes one device call per device holding pools
+        self._write_calls = len(kvcache.device_groups(
+            jax.tree_util.tree_leaves(self.pools)))
 
     # ------------------------------------------------------------- model
     def _decode_impl(self, params, pools, block_tables, tokens, lengths):
@@ -219,8 +226,12 @@ class ServingEngine:
         import repro
         traced = plan.traced
         if traced is None or traced.program is None:
-            traced = repro.trace(self._decode_impl,
-                                 *self._decode_example_args(), record=True)
+            # the pools by shape only: the trace keeps its example
+            # arguments, and write_prompt donates these buffers
+            params, pools, *rest = self._decode_example_args()
+            traced = repro.trace(self._decode_impl, params,
+                                 jax.eval_shape(lambda: pools), *rest,
+                                 record=True)
             plan.bind(traced)
         devs = plan._jax_devices(self._devices, self._device_map)
         n_params = len(jax.tree_util.tree_leaves(self.params))
@@ -300,6 +311,8 @@ class ServingEngine:
                 self.pools = kvcache.write_prompt(
                     self.pools, req.blocks, caches, j, len(a.prompt),
                     self.block_size)
+            self.stats.prompt_writes += self._write_calls
+            self.stats.prompt_write_blocks += len(req.blocks)
             req.emit(int(nxt[j]), now)
             self.stats.generated_tokens += 1
             req.state = RequestState.DECODE

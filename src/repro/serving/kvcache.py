@@ -30,6 +30,11 @@ the persistent footprint is the pool. ``scatter_token`` writes back only
 the one token each row appended, into the block its table maps that
 position to.
 
+Admission writes a prefilled prompt into its blocks with
+:func:`write_prompt`: one jitted scatter per request per device holding
+pool leaves, with the pools donated, so the blocks are written in place
+and no pool leaf is copied.
+
 Placement-aware residency
 -------------------------
 With a partition plan, each pool leaf is allocated on the device the
@@ -40,6 +45,7 @@ partition, not the other way around.
 """
 from __future__ import annotations
 
+import functools
 from typing import Any
 
 import jax
@@ -226,33 +232,81 @@ def write_prompt(pools, blocks: list[int], dense_caches, row: int,
     """Copy one prefilled request's cache rows ``[0, plen)`` from the
     dense prefill caches (row ``row``) into its allocated ``blocks``.
 
-    Host-side (runs once per admission, outside the jitted step); each
-    chunk is committed to the destination pool leaf's device first, so
-    placement-aware pools never see cross-device ops.
+    Runs once per admission, outside the jitted step, as one jitted
+    scatter per pool device (:func:`device_groups`): the pools are
+    donated, so XLA writes the request's blocks in place instead of
+    copying each leaf. The request's dense row is committed to each
+    group's device first, so placement-aware pools never see
+    cross-device ops. Positions at or past ``plen`` in the last block
+    keep their contents, and blocks past ``ceil(plen / block_size)``
+    are left alone.
+
+    The compiled scatter depends only on the shapes of the pool leaves
+    and of the dense row (its padded length ``S``): ``blocks`` is
+    padded to ``ceil(S / block_size)`` ids, and ``plen`` is traced.
     """
-    def one(path, pool, dense):
-        b = _bdim(path)
-        # dense leaf: (..., B, S, *t) — take this request's row
-        sl = [slice(None)] * dense.ndim
-        sl[b] = row
-        drow = dense[tuple(sl)]                               # (..., S, *t)
-        dev = _leaf_device(pool)
-        for i, bid in enumerate(blocks):
-            lo = i * block_size
-            n = min(block_size, plen - lo)
-            if n <= 0:
-                break
-            csl = [slice(None)] * drow.ndim
-            csl[b] = slice(lo, lo + n)
-            chunk = drow[tuple(csl)].astype(pool.dtype)
-            if dev is not None:
-                chunk = jax.device_put(chunk, dev)
-            psl = [slice(None)] * pool.ndim
-            psl[b] = bid
-            psl[b + 1] = slice(0, n)
-            pool = pool.at[tuple(psl)].set(chunk)
-        return pool
-    return jax.tree_util.tree_map_with_path(one, pools, dense_caches)
+    leaves, treedef = jax.tree_util.tree_flatten_with_path(pools)
+    dense = jax.tree_util.tree_leaves(dense_caches)
+    bdims = [_bdim(path) for path, _ in leaves]
+    out = [leaf for _, leaf in leaves]
+    rows = _take_row(tuple(dense), np.int32(row), tuple(bdims))
+    b0 = bdims[0]
+    nb, S = out[0].shape[b0], rows[0].shape[b0]
+    if plen > S:
+        raise ValueError(f"plen {plen} exceeds the prefill length {S}")
+    ids = np.full((-(-S // block_size),), nb, np.int32)  # nb: dropped
+    n = min(len(blocks), -(-plen // block_size))
+    ids[:n] = blocks[:n]
+    for dev, idx in device_groups(out).items():
+        grows = tuple(rows[i] if dev is None or _leaf_device(rows[i]) == dev
+                      else jax.device_put(rows[i], dev) for i in idx)
+        new = _scatter_rows(tuple(out[i] for i in idx), grows, ids,
+                            np.int32(plen), tuple(bdims[i] for i in idx))
+        for i, leaf in zip(idx, new):
+            out[i] = leaf
+    return jax.tree_util.tree_unflatten(treedef, out)
+
+
+@functools.partial(jax.jit, static_argnums=(2,))
+def _take_row(dense, row, bdims):
+    """Row ``row`` of each dense leaf: ``(..., B, S, *t)`` ->
+    ``(..., S, *t)``."""
+    return tuple(jax.lax.dynamic_index_in_dim(d, row, b, keepdims=False)
+                 for d, b in zip(dense, bdims))
+
+
+@functools.partial(jax.jit, static_argnums=(4,), donate_argnums=(0,))
+def _scatter_rows(pools, rows, ids, plen, bdims):
+    """``write_prompt``'s device half: scatter each dense row
+    ``(..., S, *t)`` into its pool leaf ``(..., nb, bs, *t)`` at the
+    block ids ``ids`` (``ceil(S / bs)`` of them, ``nb`` where there is
+    no block to write), keeping positions ``>= plen``."""
+    out = []
+    for pool, drow, b in zip(pools, rows, bdims):
+        bs = pool.shape[b + 1]
+        nw = ids.shape[0]
+        pad = [(0, 0)] * drow.ndim
+        pad[b] = (0, nw * bs - drow.shape[b])
+        new = jnp.pad(drow, pad).reshape(
+            drow.shape[:b] + (nw, bs) + drow.shape[b + 1:]
+        ).astype(pool.dtype)                              # (...,nw,bs,*t)
+        old = jnp.take(pool, ids, axis=b, mode="clip")
+        pos = jnp.arange(nw * bs, dtype=jnp.int32).reshape(nw, bs)
+        keep = (pos < plen).reshape((1,) * b + (nw, bs)
+                                    + (1,) * (drow.ndim - b - 1))
+        sl = (slice(None),) * b + (ids,)
+        out.append(pool.at[sl].set(jnp.where(keep, new, old), mode="drop"))
+    return tuple(out)
+
+
+def device_groups(leaves) -> dict:
+    """Leaf indices grouped by the one device each leaf lives on (key
+    ``None`` for a leaf on several devices or on none), in leaf order:
+    the calls :func:`write_prompt` makes."""
+    groups: dict = {}
+    for i, leaf in enumerate(leaves):
+        groups.setdefault(_leaf_device(leaf), []).append(i)
+    return groups
 
 
 def _leaf_device(leaf):
@@ -297,5 +351,5 @@ def place_pools(plan, n_params_leaves: int, pools, devices: list):
 __all__ = [
     "NULL_BLOCK", "OutOfBlocks", "BlockAllocator", "supported_reason",
     "init_pools", "gather_pages", "scatter_token", "write_prompt",
-    "resolve_pool_devices", "place_pools",
+    "device_groups", "resolve_pool_devices", "place_pools",
 ]

@@ -20,7 +20,7 @@ from repro.serving import (BlockAllocator, OutOfBlocks, Request,
                            RequestState, Scheduler, ServingEngine,
                            gather_pages, init_pools, poisson_workload,
                            run_workload, scatter_token, summarize,
-                           supported_reason)
+                           supported_reason, write_prompt)
 
 KEY = jax.random.PRNGKey(0)
 
@@ -105,6 +105,112 @@ def test_gather_scatter_roundtrip(setup):
         # one position per batch row changed, by +7
         diff = (leaf != orig)
         assert diff.any()
+
+
+def _write_prompt_loop(pools, blocks, dense_caches, row, plen, block_size):
+    """The block-by-block reference for ``write_prompt``: one
+    ``.at[].set`` per block and leaf of the request's row."""
+    def one(path, pool, dense):
+        b = 1 if "periods" in [getattr(p, "key", None) for p in path] else 0
+        sl = [slice(None)] * dense.ndim
+        sl[b] = row
+        drow = dense[tuple(sl)]
+        for i, bid in enumerate(blocks):
+            lo = i * block_size
+            n = min(block_size, plen - lo)
+            if n <= 0:
+                break
+            csl = [slice(None)] * drow.ndim
+            csl[b] = slice(lo, lo + n)
+            psl = [slice(None)] * pool.ndim
+            psl[b] = bid
+            psl[b + 1] = slice(0, n)
+            pool = pool.at[tuple(psl)].set(drow[tuple(csl)].astype(pool.dtype))
+        return pool
+    return jax.tree_util.tree_map_with_path(one, pools, dense_caches)
+
+
+def _prompt_case(layout, nb, bs, B, S, seed=0):
+    """Random pools (bf16) and dense prefill caches (f32) holding one
+    periods-stacked leaf (block axis 1) or one flat leaf (axis 0)."""
+    rng = np.random.default_rng(seed)
+
+    def arr(shape, dt):
+        return jnp.asarray(rng.standard_normal(shape), dt)
+    if layout == "periods":
+        return ({"periods": {"k": arr((2, nb, bs, 2, 3), jnp.bfloat16)}},
+                {"periods": {"k": arr((2, B, S, 2, 3), jnp.float32)}})
+    return ({"prelude": [{"k": arr((nb, bs, 2, 3), jnp.bfloat16)}]},
+            {"prelude": [{"k": arr((B, S, 2, 3), jnp.float32)}]})
+
+
+@pytest.mark.parametrize("layout", ["periods", "flat"])
+@pytest.mark.parametrize("row", [0, 3])
+@pytest.mark.parametrize("S,plen,blocks", [
+    (16, 1, [5, 2, 9, 7]), (16, 3, [5, 2, 9, 7]), (16, 4, [5, 2, 9, 7]),
+    (16, 8, [5, 2, 9, 7]), (16, 16, [5, 2, 9, 7]),
+    (16, 3, [9]), (16, 4, [9]),                   # warm-up's short lists
+    (6, 5, [5, 2]),                                # S not a block multiple
+])
+def test_write_prompt_matches_block_loop(layout, row, S, plen, blocks):
+    """The donated scatter leaves the pools bit-for-bit as the
+    block-by-block loop does: the row's first ``plen`` positions in its
+    blocks, everything else (tails of the last block, other blocks)
+    untouched."""
+    bs, nb, B = 4, 12, 4
+    pools, dense = _prompt_case(layout, nb, bs, B, S)
+    want = _write_prompt_loop(pools, blocks, dense, row, plen, bs)
+    got = write_prompt(jax.tree_util.tree_map(jnp.copy, pools), blocks,
+                       dense, row, plen, bs)
+    for w, g in zip(jax.tree_util.tree_leaves(want),
+                    jax.tree_util.tree_leaves(got)):
+        assert g.dtype == w.dtype and g.shape == w.shape
+        np.testing.assert_array_equal(np.asarray(g).view(np.uint16),
+                                      np.asarray(w).view(np.uint16))
+
+
+def test_write_prompt_compiles_once_per_shape(caplog):
+    """After a first write at a given (B, S), writes of other rows,
+    lengths and block lists compile nothing; the pools passed in are
+    donated."""
+    bs, nb, B, S = 4, 12, 4, 16
+    pools, dense = _prompt_case("periods", nb, bs, B, S)
+    pools = write_prompt(pools, [1, 2, 3, 4], dense, 0, 16, bs)
+    with jax.log_compiles(True):
+        for row, plen, blocks in ((3, 1, [6]), (1, 7, [8, 10]),
+                                  (2, 13, [11, 5, 9, 7])):
+            old = pools
+            pools = write_prompt(pools, blocks, dense, row, plen, bs)
+            assert all(x.is_deleted() for x in jax.tree_util.tree_leaves(old))
+    jax.block_until_ready(pools)
+    assert not [r for r in caplog.records if "Compiling" in r.getMessage()]
+
+
+def test_write_prompt_counters_and_back_to_back_ticks(setup):
+    """``prompt_writes`` counts one device call per admission on one
+    device and ``prompt_write_blocks`` the prompt blocks written; two
+    ticks in a row decode from the pools the donated write returned,
+    and the jitted engine still matches the reference."""
+    cfg, params = setup
+    rng = np.random.default_rng(11)
+    bs = 4
+    prompts = [rng.integers(1, cfg.vocab_size, n).astype(np.int32)
+               for n in (3, 9, 4)]
+    eng = ServingEngine(cfg, params, block_size=bs, num_blocks=32,
+                        max_batch=4, max_len=32)
+    for i, p in enumerate(prompts):
+        eng.submit(Request(rid=i, prompt=p, max_new_tokens=4))
+    before = jax.tree_util.tree_leaves(eng.pools)
+    assert eng.tick() == 2 * len(prompts)          # admit + decode each
+    assert all(x.is_deleted() for x in before)     # written in place
+    assert eng.tick() == len(prompts)              # decode from them
+    done = eng.run_until_drained()
+    st = eng.stats
+    assert st.prompt_writes == st.admitted == len(prompts)
+    assert st.prompt_write_blocks == sum(-(-len(p) // bs) for p in prompts)
+    assert st.to_dict()["prompt_writes"] == st.prompt_writes
+    for i, p in enumerate(prompts):
+        assert done[i].output == _reference_decode(cfg, params, p, 4), i
 
 
 def test_supported_reason_gates_recurrent_archs():
