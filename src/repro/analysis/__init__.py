@@ -44,7 +44,7 @@ from .passes import (PASSES, AnalysisContext, InterpResult,
 __all__ = [
     "analyze", "analyze_plan", "Diagnostic", "DiagnosticReport",
     "AnalysisContext", "InterpResult", "OverlapInterpResult",
-    "abstract_interpret", "overlap_interpret", "PASSES",
+    "abstract_interpret", "overlap_interpret", "certified_peaks", "PASSES",
     "CODES", "SEVERITIES", "ERROR", "WARN", "INFO",
 ]
 
@@ -56,7 +56,7 @@ _SCHEDULE_PASSES = ("structure", "deadlock", "liveness", "memory",
 def analyze(prog=None, assignment=None, k: int = 1, *, schedule=None,
             graph=None, mem_caps=None, feasible=None,
             predicted_peaks=None,
-            transfer_window_bytes=None) -> DiagnosticReport:
+            transfer_window_bytes=None, resident=None) -> DiagnosticReport:
     """Run every applicable pass; never raises on a corrupt schedule.
 
     Args:
@@ -77,13 +77,17 @@ def analyze(prog=None, assignment=None, k: int = 1, *, schedule=None,
         transfer_window_bytes: the in-flight transfer window the overlap
             pass certifies RP040 against (None: the runtime's own
             resolution — ``REPRO_TRANSFER_WINDOW_MB`` or 64 MiB).
+        resident: per-node bytes kept beside each graph input on its
+            device (e.g. an optimizer's master copy), charged by the
+            memory certificates for the whole call.
     """
     rep = DiagnosticReport()
     a = None if assignment is None else np.asarray(assignment)
     ctx = AnalysisContext(prog=prog, assignment=a, k=int(k),
                           schedule=schedule, graph=graph, mem_caps=mem_caps,
                           feasible=feasible, predicted_peaks=predicted_peaks,
-                          transfer_window_bytes=transfer_window_bytes)
+                          transfer_window_bytes=transfer_window_bytes,
+                          resident=resident)
     PASSES["placement"](ctx, rep)
     rep.passes_run.append("placement")
     if prog is None:
@@ -116,6 +120,20 @@ def analyze(prog=None, assignment=None, k: int = 1, *, schedule=None,
         PASSES[name](ctx, rep)
         rep.passes_run.append(name)
     return rep
+
+
+def certified_peaks(prog, assignment, k: int, graph,
+                    resident=None) -> np.ndarray:
+    """Per-device peak bytes the runtime's schedule keeps live: the larger
+    of the ``memory`` pass's certificate (lazy transfers) and the
+    ``overlap`` pass's (prefetch-at-producer), the two RP020 and RP040
+    hold against the capacity."""
+    a = np.asarray(assignment)
+    ctx = AnalysisContext(prog=prog, assignment=a, k=int(k), graph=graph,
+                          resident=resident)
+    ctx.schedule = cut_segments(prog, a, k=ctx.k)
+    return np.maximum(abstract_interpret(ctx).cert_peaks,
+                      overlap_interpret(ctx).cert_peaks)
 
 
 def analyze_plan(plan: Any, *, graph: Any = None) -> DiagnosticReport:
@@ -160,7 +178,8 @@ def analyze_plan(plan: Any, *, graph: Any = None) -> DiagnosticReport:
         mem_caps=plan.devices.mem_caps() if plan.devices is not None
         else None,
         feasible=bool(plan.report.feasible),
-        predicted_peaks=pred if getattr(pred, "size", 0) else None)
+        predicted_peaks=pred if getattr(pred, "size", 0) else None,
+        resident=getattr(plan, "resident", None))
     rep.passes_run.insert(0, "artifact")
     rep.diagnostics[:0] = pre
     return rep

@@ -81,6 +81,9 @@ class AnalysisContext:
     # against (None: REPRO_TRANSFER_WINDOW_MB env or the 64 MiB default,
     # same resolution the runtime uses)
     transfer_window_bytes: float | None = None
+    # per-node bytes kept beside a graph input on its device for the
+    # whole call (an optimizer's master copy), charged with the input
+    resident: np.ndarray | None = None
     # caches shared between passes
     _interp: "InterpResult | None" = field(default=None, repr=False)
     _overlap: "OverlapInterpResult | None" = field(default=None, repr=False)
@@ -325,6 +328,18 @@ def _slot_bytes(ctx: AnalysisContext, slot: Slot) -> float:
     return float(mem[nid]) / n_out
 
 
+def _alloc_roots(ctx: AnalysisContext, alloc: Callable) -> None:
+    """Charge the graph inputs and constants, and the resident state kept
+    beside each, to their devices for the whole call."""
+    prog = ctx.prog
+    res = ctx.resident
+    for nid in list(prog.input_nodes) + [n for n, _ in prog.const_nodes]:
+        nb = _slot_bytes(ctx, (nid, 0)) * max(prog.n_outputs.get(nid, 1), 1)
+        if res is not None:
+            nb += float(res[nid])
+        alloc(ctx.dev(nid), nb)
+
+
 def abstract_interpret(ctx: AnalysisContext) -> InterpResult:
     """Replay the compiled runtime's refcount/donation/transfer schedule
     abstractly — the exact control flow of ``CompiledRuntime.__call__``
@@ -371,9 +386,7 @@ def abstract_interpret(ctx: AnalysisContext) -> InterpResult:
 
     # residents: graph inputs and constants, committed for the whole call
     if track_bytes:
-        for nid in list(prog.input_nodes) + [n for n, _ in prog.const_nodes]:
-            alloc(ctx.dev(nid), _slot_bytes(ctx, (nid, 0))
-                  * max(prog.n_outputs.get(nid, 1), 1))
+        _alloc_roots(ctx, alloc)
     resident = live_b.copy()
 
     # static maps: who produces / reads every slot (schedule positions)
@@ -674,9 +687,7 @@ def overlap_interpret(ctx: AnalysisContext) -> OverlapInterpResult:
 
     roots = set(prog.input_nodes) | {nid for nid, _ in prog.const_nodes}
     if track:
-        for nid in list(prog.input_nodes) + [n for n, _ in prog.const_nodes]:
-            alloc(ctx.dev(nid), _slot_bytes(ctx, (nid, 0))
-                  * max(prog.n_outputs.get(nid, 1), 1))
+        _alloc_roots(ctx, alloc)
 
     segs = sched.segments
     slots_by_producer: dict[int, list[Slot]] = {}

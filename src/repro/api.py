@@ -365,6 +365,9 @@ class PartitionPlan:
     names: np.ndarray | None = None       # per-node op names (optional)
     schema_version: int = PLAN_SCHEMA_VERSION
     traced: TracedModel | None = None     # not serialized
+    # per-node bytes kept beside a graph input on its device (an
+    # optimizer's master copy); None: nothing beside the inputs
+    resident: np.ndarray | None = None
 
     # -- convenience views --------------------------------------------------
     @property
@@ -446,6 +449,8 @@ class PartitionPlan:
                                          dtype=np.float64)}
         if self.names is not None:
             arrays["names"] = np.asarray(self.names)
+        if self.resident is not None:
+            arrays["resident"] = np.asarray(self.resident, dtype=np.float64)
         with open(apath, "wb") as f:
             np.savez(f, **arrays)
         header = {
@@ -497,6 +502,8 @@ class PartitionPlan:
             assignment = np.asarray(z["assignment"], dtype=np.int64)
             peak_mem = np.asarray(z["peak_mem"], dtype=np.float64)
             names = np.asarray(z["names"]) if "names" in z.files else None
+            resident = (np.asarray(z["resident"], dtype=np.float64)
+                        if "resident" in z.files else None)
         digest = hashlib.sha256(
             np.ascontiguousarray(assignment).tobytes()).hexdigest()
         if digest != header["assignment_sha256"]:
@@ -518,7 +525,7 @@ class PartitionPlan:
                    fingerprint=header["graph_fingerprint"], report=report,
                    devices=DeviceSpec(int(header["devices"]), mem),
                    meta=dict(header.get("meta") or {}), names=names,
-                   schema_version=int(ver))
+                   schema_version=int(ver), resident=resident)
         if traced is not None or graph is not None:
             plan.bind(traced if traced is not None
                       else TracedModel(graph, None, graph.fingerprint()))
@@ -974,7 +981,8 @@ def partition(traced_or_graph: TracedModel | CostGraph,
               memory: float | Sequence[float] | None = None,
               options: PardnnOptions | None = None,
               progress: Callable[[str, dict], None] | None = None,
-              meta: dict | None = None) -> PartitionPlan:
+              meta: dict | None = None,
+              resident: Sequence[float] | None = None) -> PartitionPlan:
     """Partition a traced model (or raw cost graph) into a
     :class:`PartitionPlan`.
 
@@ -990,6 +998,18 @@ def partition(traced_or_graph: TracedModel | CostGraph,
             through the partitioner's stages and Step-2 rounds.
         meta: Free-form JSON-serializable metadata stored in the plan
             header (arch name, config hash, …).
+        resident: Bytes kept beside each flat input leaf of the traced
+            function, in its order, on whichever device holds the leaf
+            (an optimizer's float32 master beside a bf16 weight is twice
+            the weight's bytes). Step-2 and the verifier's certificates
+            charge them; they are never shipped. Needs a recorded
+            program.
+
+    With a recorded program and a capacity, Step-2 is held to the
+    verifier's memory certificate: where the peaks the runtime's segment
+    schedule keeps live exceed a device's capacity, Step-2's overflow
+    stage runs again below it (``pardnn_partition``'s ``certify``), so a
+    plan marked feasible is one :meth:`PartitionPlan.verify` accepts.
     """
     if isinstance(traced_or_graph, TracedModel):
         traced = traced_or_graph
@@ -1002,16 +1022,37 @@ def partition(traced_or_graph: TracedModel | CostGraph,
             f"partition() takes a TracedModel or CostGraph, got "
             f"{type(traced_or_graph).__name__}")
     spec = DeviceSpec.resolve(devices, memory)
+    prog = traced.program
+    per_node = None
+    if resident is not None:
+        if prog is None:
+            raise ValueError("resident= needs a program recorded with "
+                             "trace(..., record=True)")
+        if len(resident) != len(prog.input_nodes):
+            raise ValueError(f"resident= has {len(resident)} entries; the "
+                             f"program has {len(prog.input_nodes)} input "
+                             f"leaves")
+        per_node = np.zeros(traced.graph.n)
+        per_node[np.asarray(prog.input_nodes, dtype=np.int64)] = \
+            np.asarray(resident, dtype=np.float64)
+    certify = None
+    if prog is not None and spec.mem_caps() is not None:
+        from .analysis import certified_peaks
+
+        def certify(assignment):
+            return certified_peaks(prog, assignment, spec.count,
+                                   traced.graph, per_node)
     placement = pardnn_partition(traced.graph, spec.count,
                                  mem_caps=spec.mem_caps(), options=options,
-                                 progress=progress)
+                                 progress=progress, resident=per_node,
+                                 certify=certify)
     return PartitionPlan(
         assignment=np.asarray(placement.assignment, dtype=np.int64),
         k=spec.count, fingerprint=traced.fingerprint,
         report=PlanReport.from_placement(placement), devices=spec,
         meta=dict(meta or {}),
         names=np.asarray(traced.graph.names) if traced.graph.names else None,
-        traced=traced)
+        traced=traced, resident=per_node)
 
 
 __all__ = [

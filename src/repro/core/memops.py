@@ -99,11 +99,17 @@ def _free_after(t: float) -> float:
 
 
 def compute_profile(g: CostGraph, assignment: np.ndarray, sched: Schedule,
-                    k: int, engine: str | None = None) -> MemoryProfile:
-    """Per-device memory profile of a schedule; dispatches on ``engine``."""
+                    k: int, engine: str | None = None,
+                    resident: np.ndarray | None = None) -> MemoryProfile:
+    """Per-device memory profile of a schedule; dispatches on ``engine``.
+
+    ``resident``: per-node bytes kept beside a node's output on whichever
+    pe holds the node for the whole horizon (state beside an input leaf,
+    such as an optimizer's master copy); charged like a residual output
+    but never shipped to a consumer."""
     if resolve_engine(engine) == "scalar":
-        return compute_profile_scalar(g, assignment, sched, k)
-    return compute_profile_vectorized(g, assignment, sched, k)
+        return compute_profile_scalar(g, assignment, sched, k, resident)
+    return compute_profile_vectorized(g, assignment, sched, k, resident)
 
 
 # --------------------------------------------------------------- vectorized
@@ -180,8 +186,9 @@ def _event_table(g: CostGraph, assignment: np.ndarray, sched: Schedule,
 
 
 def compute_profile_vectorized(g: CostGraph, assignment: np.ndarray,
-                               sched: Schedule, k: int) -> MemoryProfile:
-    n = g.n
+                               sched: Schedule, k: int,
+                               resident: np.ndarray | None = None
+                               ) -> MemoryProfile:
     mem = np.asarray(g.mem)
     ntype = np.asarray(g.ntype)
     pu = np.asarray(assignment, dtype=np.int64)
@@ -189,6 +196,8 @@ def compute_profile_vectorized(g: CostGraph, assignment: np.ndarray,
     res_mask = (ntype == RESIDUAL) & (mem != 0)
     residual = np.bincount(pu[res_mask], weights=mem[res_mask],
                            minlength=k).astype(np.float64)
+    if resident is not None:
+        residual += np.bincount(pu, weights=resident, minlength=k)
 
     lc = _last_consumers(g, pu, sched.st, k)
     ev_pe, ev_time, ev_delta, _ = _event_table(g, pu, sched, k, lc)
@@ -222,7 +231,9 @@ def compute_profile_vectorized(g: CostGraph, assignment: np.ndarray,
 
 # ------------------------------------------------------------------- scalar
 def compute_profile_scalar(g: CostGraph, assignment: np.ndarray,
-                           sched: Schedule, k: int) -> MemoryProfile:
+                           sched: Schedule, k: int,
+                           resident: np.ndarray | None = None
+                           ) -> MemoryProfile:
     """Reference sweep over nodes in id order (executable documentation)."""
     n = g.n
     mem = np.asarray(g.mem)
@@ -230,6 +241,9 @@ def compute_profile_scalar(g: CostGraph, assignment: np.ndarray,
     st = sched.st
 
     residual = np.zeros(k)
+    if resident is not None:
+        for u in np.flatnonzero(resident):
+            residual[int(assignment[u])] += resident[u]
     events: list[list[tuple[float, float]]] = [[] for _ in range(k)]
 
     # last consumer of each node's output per holding pe (by start time)
@@ -290,23 +304,29 @@ def compute_profile_scalar(g: CostGraph, assignment: np.ndarray,
 # ----------------------------------------------------------- M_pot (Table 1)
 def memory_potentials(g: CostGraph, assignment: np.ndarray, sched: Schedule,
                       prof: MemoryProfile, pe: int, t: float,
-                      engine: str | None = None) -> dict[int, float]:
+                      engine: str | None = None,
+                      resident: np.ndarray | None = None
+                      ) -> dict[int, float]:
     """M_pot(n, t) for nodes assigned to ``pe`` (Table 1).
 
     The memory that would be released on ``pe`` at time t if node n were
     moved elsewhere: outputs of direct ancestors executed before t for
     which n is the last local descendant, plus n's own output if n is
     executing at t, plus n's residual footprint (moving a variable moves
-    its storage).
+    its storage, and the ``resident`` state kept beside it).
     """
     if resolve_engine(engine) == "scalar":
-        return memory_potentials_scalar(g, assignment, sched, prof, pe, t)
-    return memory_potentials_vectorized(g, assignment, sched, prof, pe, t)
+        return memory_potentials_scalar(g, assignment, sched, prof, pe, t,
+                                        resident)
+    return memory_potentials_vectorized(g, assignment, sched, prof, pe, t,
+                                        resident)
 
 
 def memory_potentials_vectorized(g: CostGraph, assignment: np.ndarray,
                                  sched: Schedule, prof: MemoryProfile,
-                                 pe: int, t: float) -> dict[int, float]:
+                                 pe: int, t: float,
+                                 resident: np.ndarray | None = None
+                                 ) -> dict[int, float]:
     n = g.n
     mem = np.asarray(g.mem)
     ntype = np.asarray(g.ntype)
@@ -316,6 +336,8 @@ def memory_potentials_vectorized(g: CostGraph, assignment: np.ndarray,
 
     base = np.where(ntype == RESIDUAL, mem,
                     np.where((st <= t) & (t <= ft), mem, 0.0))
+    if resident is not None:
+        base = base + resident
     base = np.where(on_pe, base, 0.0)
 
     # held inputs: edges a -> u (u on pe, st[u] >= t) whose source a is
@@ -341,7 +363,9 @@ def memory_potentials_vectorized(g: CostGraph, assignment: np.ndarray,
 
 def memory_potentials_scalar(g: CostGraph, assignment: np.ndarray,
                              sched: Schedule, prof: MemoryProfile,
-                             pe: int, t: float) -> dict[int, float]:
+                             pe: int, t: float,
+                             resident: np.ndarray | None = None
+                             ) -> dict[int, float]:
     mem = np.asarray(g.mem)
     ntype = np.asarray(g.ntype)
     st, ft = sched.st, sched.ft
@@ -354,6 +378,8 @@ def memory_potentials_scalar(g: CostGraph, assignment: np.ndarray,
             p += mem[u]
         elif st[u] <= t <= ft[u]:
             p += mem[u]
+        if resident is not None:
+            p += resident[u]
         if st[u] >= t:  # not yet executed: its held inputs would be freed
             for a in esrc[indptr_in[u]:indptr_in[u + 1]]:
                 if ntype[a] == REF:
@@ -380,7 +406,7 @@ class IncrementalMemoryTracker:
     """
 
     def __init__(self, g: CostGraph, assignment: np.ndarray, sched: Schedule,
-                 k: int):
+                 k: int, resident: np.ndarray | None = None):
         self.g = g
         self.k = k
         self.sched = sched
@@ -395,9 +421,14 @@ class IncrementalMemoryTracker:
             st, ft, np.nextafter(st, np.inf), np.nextafter(ft, np.inf)]))
         self.times = times
         self.trees = [MaxPrefixTree(times.size) for _ in range(k)]
+        # what moves with a node besides its events: a residual output
+        # and the resident state kept beside it
+        self.carried = np.where(self.ntype == RESIDUAL, self.mem, 0.0)
+        if resident is not None:
+            self.carried = self.carried + resident
         self.residual = np.zeros(k)
-        res_mask = (self.ntype == RESIDUAL) & (self.mem != 0)
-        np.add.at(self.residual, assignment[res_mask], self.mem[res_mask])
+        res_mask = self.carried != 0
+        np.add.at(self.residual, assignment[res_mask], self.carried[res_mask])
 
         lc = _last_consumers(g, assignment, st, k)
         ev_pe, ev_time, ev_delta, _ = _event_table(g, assignment, sched, k,
@@ -461,9 +492,9 @@ class IncrementalMemoryTracker:
         new = [e for x in touched for e in self._node_events(x)]
         self._apply_events(old, -1.0)
         self._apply_events(new, +1.0)
-        if self.ntype[u] == RESIDUAL and self.mem[u] != 0:
-            self.residual[from_pe] -= self.mem[u]
-            self.residual[to_pe] += self.mem[u]
+        if self.carried[u] != 0:
+            self.residual[from_pe] -= self.carried[u]
+            self.residual[to_pe] += self.carried[u]
         return {"node": u, "from": from_pe, "to": to_pe,
                 "old": old, "new": new}
 
@@ -472,6 +503,6 @@ class IncrementalMemoryTracker:
         self._apply_events(token["new"], -1.0)
         self._apply_events(token["old"], +1.0)
         self.assignment[u] = token["from"]
-        if self.ntype[u] == RESIDUAL and self.mem[u] != 0:
-            self.residual[token["to"]] -= self.mem[u]
-            self.residual[token["from"]] += self.mem[u]
+        if self.carried[u] != 0:
+            self.residual[token["to"]] -= self.carried[u]
+            self.residual[token["from"]] += self.carried[u]

@@ -147,11 +147,15 @@ def address_overflow(g: CostGraph, assignment: np.ndarray, pe: int,
         order = np.argsort(-headroom)
         target = -1
         if exact:
-            for c_pe in order:
+            # cheap M_pot prefilter first; the tracker then verifies the
+            # surviving target exactly (and rolls back misfits). A device
+            # whose global peak leaves less headroom than M_pot may still
+            # host the node when its peak lies at another time, so the
+            # targets the prefilter ruled out are tried exactly last
+            for c_pe in ([c for c in order if headroom[c] >= pot]
+                         + [c for c in order if headroom[c] < pot]):
                 c_pe = int(c_pe)
-                # cheap M_pot prefilter first; the tracker then verifies
-                # the surviving target exactly (and rolls back misfits)
-                if c_pe == pe or headroom[c_pe] < pot:
+                if c_pe == pe:
                     continue
                 tokens = [tracker.apply_move(nm, c_pe) for nm in group]
                 if tracker.peak(c_pe) <= caps[c_pe] + 1e-9:

@@ -31,6 +31,11 @@ from .refinement import refine_cluster_swaps, refine_node_switching
 from .slicing import slice_graph
 
 
+#: share of a device's capacity that a device whose certificate
+#: overflowed is re-planned below, beyond the gap the certificate showed
+CERT_MARGIN = 0.01
+
+
 @dataclass
 class PardnnOptions:
     """Tuning knobs for :func:`pardnn_partition`.
@@ -69,7 +74,9 @@ class PardnnOptions:
 def pardnn_partition(g: CostGraph, k: int,
                      mem_caps: np.ndarray | float | None = None,
                      options: PardnnOptions | None = None,
-                     progress: Callable[[str, dict], None] | None = None
+                     progress: Callable[[str, dict], None] | None = None,
+                     resident: np.ndarray | None = None,
+                     certify: Callable[[np.ndarray], np.ndarray] | None = None
                      ) -> Placement:
     """Partition cost graph ``g`` across ``k`` devices (the full ParDNN
     algorithm, Algorithms 1-2 + Step-2).
@@ -88,6 +95,18 @@ def pardnn_partition(g: CostGraph, k: int,
             dict of counters for that stage — lets long partitions (100k+
             node graphs) report liveness to callers such as
             :func:`repro.api.partition`.
+        resident: Optional per-node bytes kept beside a node on whichever
+            device holds it (e.g. an optimizer's float32 master beside a
+            weight leaf): charged by Step-2 like a residual output, moved
+            with the node, never shipped to its consumers.
+        certify: Optional ``certify(assignment) -> per-device bytes``,
+            the peaks the runtime's own schedule keeps live (the static
+            verifier's certificate). Where it exceeds ``memory_fraction``
+            of ``mem_caps`` after Step-2, the device's Step-2 cap is
+            lowered by the gap between the certificate and Step-2's own
+            peak and Step-2's overflow stage runs again, until the
+            certificate holds or nothing moves. ``feasible`` then also
+            needs the certificate within ``mem_caps``.
 
     Returns:
         :class:`~repro.core.graph.Placement` with the node→device
@@ -147,62 +166,55 @@ def pardnn_partition(g: CostGraph, k: int,
     # ---------------- Step-2 ----------------
     moved_total = 0
     step2_rounds = 0
-    feasible = True
+    cert_rounds = 0
     pinned: set[int] = set()
     caps = None
+    certified = None
     if mem_caps is not None:
-        caps = (np.full(k, float(mem_caps)) if np.isscalar(mem_caps)
+        full = (np.full(k, float(mem_caps)) if np.isscalar(mem_caps)
                 else np.asarray(mem_caps, dtype=np.float64))
-        caps = caps * opt.memory_fraction
-        for _ in range(opt.max_memory_rounds):
-            round_span = _span("partition/step2_round",
-                               round=step2_rounds + 1).__enter__()
-            sched = emulate(g, assignment, k, comm_scale=opt.comm_scale,
-                            engine=eng)
-            prof = compute_profile(g, assignment, sched, k, engine=eng)
-            overflows = prof.first_overflow(caps)
-            if not overflows:
-                feasible = True
-                round_span.__exit__(None, None, None)
+        target = full * opt.memory_fraction
+        caps = target.copy()
+        while True:
+            moved, rounds = _step2_rounds(g, assignment, k, caps, opt,
+                                          pinned, resident, notify,
+                                          step2_rounds)
+            moved_total += moved
+            step2_rounds += rounds
+            if certify is None or (cert_rounds and not moved):
                 break
-            feasible = False
-            step2_rounds += 1
-            tracker = (IncrementalMemoryTracker(g, assignment, sched, k)
-                       if opt.use_tracker else None)
-            headroom = caps - (tracker.peaks() if tracker is not None
-                               else prof.peak)
-            progressed = False
-            for pe, t_over, amount in overflows:
-                if tracker is not None:
-                    # earlier moves this round may have already relieved pe
-                    amount = tracker.peak(pe) - caps[pe]
-                    if amount <= 1e-9:
-                        continue
-                pots = memory_potentials(g, assignment, sched, prof, pe,
-                                         t_over, engine=eng)
-                res = address_overflow(g, assignment, pe, amount, pots,
-                                       headroom, pinned, tracker=tracker,
-                                       caps=caps if tracker is not None
-                                       else None)
-                moved_total += len(res.moved)
-                if res.moved:
-                    progressed = True
-            notify("step2_round", {"round": step2_rounds,
-                                   "overflowing_pes": len(overflows),
-                                   "moved_total": moved_total})
-            round_span.__exit__(None, None, None)
-            if not progressed:
-                break  # ran out of movable nodes (§3.2.3 termination)
-        else:
+            # the certificate keeps the slack Step-2 keeps for what the
+            # runtime allocates besides (each segment's temporaries)
+            certified = np.asarray(certify(assignment), dtype=np.float64)
+            over = certified > target
+            if not over.any() or cert_rounds >= opt.max_memory_rounds:
+                break
             sched = emulate(g, assignment, k, comm_scale=opt.comm_scale,
                             engine=eng)
-            prof = compute_profile(g, assignment, sched, k, engine=eng)
-            feasible = not prof.first_overflow(caps)
+            prof = compute_profile(g, assignment, sched, k, engine=eng,
+                                   resident=resident)
+            # the certificate's gap above Step-2's own peak is what the
+            # op-level model does not see on that device: plan below it
+            caps = np.where(over, np.minimum(
+                caps, target - (certified - prof.peak)
+                - CERT_MARGIN * full), caps)
+            cert_rounds += 1
+            notify("cert_round", {"round": cert_rounds,
+                                  "certified": certified.tolist(),
+                                  "caps": caps.tolist()})
+            certified = None        # stale once Step-2 moves nodes
 
     sched = emulate(g, assignment, k, comm_scale=opt.comm_scale, engine=eng)
-    prof = compute_profile(g, assignment, sched, k, engine=eng)
+    prof = compute_profile(g, assignment, sched, k, engine=eng,
+                           resident=resident)
+    feasible = True
     if caps is not None:
-        feasible = not prof.first_overflow(caps)
+        feasible = not prof.first_overflow(target)
+        if certify is not None:
+            if certified is None:
+                certified = np.asarray(certify(assignment), dtype=np.float64)
+            # what plan.verify() holds the plan to (RP020/RP040)
+            feasible = feasible and bool(np.all(certified <= full))
     t_end = time.perf_counter()
 
     notify("done", {"makespan": sched.makespan, "feasible": feasible,
@@ -221,5 +233,61 @@ def pardnn_partition(g: CostGraph, k: int,
             "mapping": m.stats,
             "refinement": ref_stats,
             "step2_rounds": step2_rounds,
+            "cert_rounds": cert_rounds,
+            "certified_peaks": (None if certified is None
+                                else certified.tolist()),
             "moved_frac": moved_total / max(g.n, 1),
         })
+
+
+def _step2_rounds(g: CostGraph, assignment: np.ndarray, k: int,
+                  caps: np.ndarray, opt: PardnnOptions, pinned: set[int],
+                  resident: np.ndarray | None,
+                  notify: Callable[[str, dict], None],
+                  done_rounds: int) -> tuple[int, int]:
+    """Step-2's rounds (emulate, track memory, knapsack moves) on
+    ``assignment`` in place, until every device's peak is under ``caps``
+    or nothing moves; returns ``(nodes moved, overflowing rounds)``."""
+    eng = opt.engine
+    moved_total = 0
+    rounds = 0
+    for _ in range(opt.max_memory_rounds):
+        round_span = _span("partition/step2_round",
+                           round=done_rounds + rounds + 1).__enter__()
+        sched = emulate(g, assignment, k, comm_scale=opt.comm_scale,
+                        engine=eng)
+        prof = compute_profile(g, assignment, sched, k, engine=eng,
+                               resident=resident)
+        overflows = prof.first_overflow(caps)
+        if not overflows:
+            round_span.__exit__(None, None, None)
+            break
+        rounds += 1
+        tracker = (IncrementalMemoryTracker(g, assignment, sched, k,
+                                            resident=resident)
+                   if opt.use_tracker else None)
+        headroom = caps - (tracker.peaks() if tracker is not None
+                           else prof.peak)
+        progressed = False
+        for pe, t_over, amount in overflows:
+            if tracker is not None:
+                # earlier moves this round may have already relieved pe
+                amount = tracker.peak(pe) - caps[pe]
+                if amount <= 1e-9:
+                    continue
+            pots = memory_potentials(g, assignment, sched, prof, pe,
+                                     t_over, engine=eng, resident=resident)
+            res = address_overflow(g, assignment, pe, amount, pots,
+                                   headroom, pinned, tracker=tracker,
+                                   caps=caps if tracker is not None
+                                   else None)
+            moved_total += len(res.moved)
+            if res.moved:
+                progressed = True
+        notify("step2_round", {"round": done_rounds + rounds,
+                               "overflowing_pes": len(overflows),
+                               "moved_total": moved_total})
+        round_span.__exit__(None, None, None)
+        if not progressed:
+            break  # ran out of movable nodes (§3.2.3 termination)
+    return moved_total, rounds

@@ -7,14 +7,17 @@ device compiled subprograms with explicit transfers.
 
 * Each :class:`~repro.core.segments.Segment` becomes one ``jax.jit``
   callable compiled exactly once (AOT via ``lower().compile()`` on the
-  first call, so compile time is accounted separately from run time).
+  first call, so compile time is accounted separately from run time);
+  the first call compiles all segments at once, in a pool of threads.
 * Cross-segment values live in a slot environment with **reference
   counts** derived from the trace-time liveness table: when the last
   consuming segment of a value has run, its buffer is dropped — live
   memory tracks the plan's predicted per-device profile instead of the
   whole graph (the interpreter's all-live behaviour).
 * Cross-device reads become explicit ``jax.device_put`` transfer ops,
-  counted (count/bytes/modelled seconds) in :class:`RuntimeStats`.
+  counted (count/bytes/modelled seconds) in :class:`RuntimeStats`; with
+  a telemetry sink on, each is a ``runtime/transfer`` span carrying its
+  ``bytes``, ``src`` and ``dst`` pe and whether it was ``prefetched``.
 * Segment inputs that die at their segment (``Segment.dead_inputs``)
   are donated to XLA so the output can reuse the input buffer.
 
@@ -55,12 +58,14 @@ from __future__ import annotations
 import os
 import time
 import warnings
+from concurrent.futures import ThreadPoolExecutor
 from dataclasses import dataclass, field
 from typing import Any
 
 import jax
 import jax.numpy as jnp
 import numpy as np
+from jax.sharding import SingleDeviceSharding
 
 from ..obs import spans as _obs
 from .costmodel import DeviceModel
@@ -399,6 +404,50 @@ class CompiledRuntime:
                     deferred=st.deferred_transfers)
         return result
 
+    def _precompile(self, node_vals: dict) -> float:
+        """Compile every segment not compiled yet, before the first
+        dispatch: each segment's input shapes follow from the call's
+        placed inputs and constants (``node_vals``) through
+        ``jax.eval_shape`` of the segments before it, each is lowered for
+        its device, and the compiles run in a pool of threads (XLA
+        compiles without holding the GIL). Returns the seconds."""
+        t0 = time.perf_counter()
+        avals: dict[Slot, Any] = {
+            (nid, 0): jax.ShapeDtypeStruct(v.shape, v.dtype,
+                                           weak_type=v.aval.weak_type)
+            for nid, v in node_vals.items()}
+        lowered = []
+
+        def compile_one(item):
+            sid, lw = item
+            with _obs.span("runtime/compile", "runtime", segment=sid):
+                return lw.compile()
+
+        with warnings.catch_warnings():
+            # CPU backends may decline donation; that is a performance
+            # hint, not an error
+            warnings.filterwarnings("ignore", message=".*donated.*",
+                                    category=UserWarning)
+            for seg, jitted in zip(self.schedule.segments, self._jits):
+                sharding = SingleDeviceSharding(self.devices[seg.device])
+                ins = [jax.ShapeDtypeStruct(avals[s].shape, avals[s].dtype,
+                                            weak_type=avals[s].weak_type,
+                                            sharding=sharding)
+                       for s in seg.inputs]
+                outs = jax.eval_shape(jitted, *ins)
+                for slot, o in zip(seg.outputs, outs):
+                    avals[slot] = o
+                if seg.sid not in self._compiled:
+                    lowered.append((seg.sid, jitted.lower(*ins)))
+            # a few threads: each compile also takes the persistent
+            # cache's file lock, which more threads only queue on
+            workers = max(1, min(len(lowered), os.cpu_count() or 1, 8))
+            with ThreadPoolExecutor(workers) as pool:
+                for (sid, _), exe in zip(lowered,
+                                         pool.map(compile_one, lowered)):
+                    self._compiled[sid] = exe
+        return time.perf_counter() - t0
+
     def _run(self, flat_args: list, sync: bool, window: float,
              traced: bool):
         """One call's dispatch loop and final wait (``__call__``)."""
@@ -448,6 +497,15 @@ class CompiledRuntime:
         xfer_cache: dict[tuple[Slot, int], Any] = {}
         cache_by_src: dict[int, list[tuple[Slot, int]]] = {}
 
+        def put(v: Any, nb: float, src_pe: int, dst_pe: int,
+                prefetched: bool) -> Any:
+            """One cross-device copy, in a ``runtime/transfer`` span."""
+            if not traced:
+                return jax.device_put(v, self.devices[dst_pe])
+            with _obs.span("runtime/transfer", "runtime", bytes=nb,
+                           src=src_pe, dst=dst_pe, prefetched=prefetched):
+                return jax.device_put(v, self.devices[dst_pe])
+
         def count_transfer(nb: float) -> None:
             st.transfers += 1
             st.transfer_bytes += nb
@@ -478,7 +536,7 @@ class CompiledRuntime:
                         obs.instant("runtime/transfer_defer", "runtime",
                                     {"bytes": nb, "device": dst_pe})
                     continue
-                v = jax.device_put(src_v, dev)
+                v = put(src_v, nb, self._pe_of(slot[0]), dst_pe, True)
                 if obs_on:
                     obs.instant("runtime/transfer_prefetch", "runtime",
                                 {"bytes": nb, "device": dst_pe,
@@ -496,6 +554,8 @@ class CompiledRuntime:
             issue_prefetch(-1)          # graph inputs/consts
 
         compile_s = 0.0
+        if len(self._compiled) < len(self._jits):
+            compile_s = self._precompile(node_vals)
         seg_seconds: list[float] = []
         dispatch_s: list[float] = []
         retained: list[tuple[tuple, list]] = []
@@ -531,7 +591,8 @@ class CompiledRuntime:
                             # the copy was already donated by an earlier
                             # reader
                             nb = float(_nbytes(v))
-                            v = jax.device_put(v, dev)
+                            v = put(v, nb, self._pe_of(slot[0]),
+                                    seg.device, False)
                             count_transfer(nb)
                             alloc(seg.device, nb)
                             if pos in donate_set:
@@ -547,21 +608,7 @@ class CompiledRuntime:
                                 and key not in self._donated_copy_keys:
                             xfer_vals.append(v)
                     invals.append(v)
-                exe = self._compiled.get(seg.sid)
-                if exe is None:
-                    t0 = time.perf_counter()
-                    with _obs.span("runtime/compile", "runtime",
-                                   segment=seg.sid), \
-                            warnings.catch_warnings():
-                        # CPU backends may decline donation; that is a
-                        # performance hint, not an error
-                        warnings.filterwarnings(
-                            "ignore", message=".*donated.*",
-                            category=UserWarning)
-                        exe = self._jits[seg.sid].lower(
-                            *invals).compile()
-                    compile_s += time.perf_counter() - t0
-                    self._compiled[seg.sid] = exe
+                exe = self._compiled[seg.sid]
                 t_seg = (time.perf_counter() if self.profile_segments
                          else 0.0)
                 with warnings.catch_warnings():

@@ -566,6 +566,64 @@ def test_runtime_spans_nest_in_plan_execute():
         assert {"mode", "segments", "transfers"} <= set(call[2])
 
 
+_TRANSFERS = """
+import json
+import jax, jax.numpy as jnp
+import repro
+from repro.obs import spans
+
+def f(w, x):
+    h = jnp.tanh(x @ w)
+    for _ in range(3):
+        h = jnp.tanh(h @ w) + h
+    return jnp.sum(h * h)
+
+key = jax.random.PRNGKey(0)
+w = jax.random.normal(key, (64, 64))
+x = jax.random.normal(key, (8, 64))
+traced = repro.trace(jax.grad(f), w, x, record=True)
+plan = repro.partition(traced, devices=jax.devices()[:4])
+out = []
+for mode in ("async", "sync"):
+    spans.enable()
+    spans.get_tracer().drain()
+    plan.execute(w, x, mode=mode)
+    ev = spans.get_tracer().drain()
+    spans.enable(False)
+    rt = plan.report.runtime
+    out.append({"mode": mode, "transfers": rt["transfers"],
+                "transfer_bytes": rt["transfer_bytes"],
+                "prefetched": rt["prefetched_transfers"],
+                "events": [[e[1], e[5], e[6], e[7]] for e in ev
+                           if e[0] == spans.PH_COMPLETE]})
+print(json.dumps(out))
+"""
+
+
+def test_transfer_spans_nest_in_the_call_and_sum_to_the_counter():
+    """On four host devices, each cross-device copy is one
+    ``runtime/transfer`` span inside the call's ``runtime/call``,
+    carrying ``bytes``, ``src``, ``dst`` and ``prefetched``; their bytes
+    and count are the runtime's ``transfer_bytes`` and ``transfers``, in
+    both dispatch modes."""
+    from repro.conformance import run_py
+    for call in json.loads(run_py(_TRANSFERS, devices=4).splitlines()[-1]):
+        ev = call["events"]
+        xfers = [e for e in ev if e[0] == "runtime/transfer"]
+        (outer,) = [e for e in ev if e[0] == "runtime/call"]
+        assert call["transfers"] > 0 and len(xfers) == call["transfers"]
+        for _, ts, dur, args in xfers:
+            assert outer[1] <= ts and ts + dur <= outer[1] + outer[2]
+            assert {"bytes", "src", "dst", "prefetched"} <= set(args)
+            assert args["src"] != args["dst"]
+            assert 0 <= args["src"] < 4 and 0 <= args["dst"] < 4
+        assert sum(a["bytes"] for *_, a in xfers) == \
+            pytest.approx(call["transfer_bytes"])
+        assert sum(bool(a["prefetched"]) for *_, a in xfers) == \
+            call["prefetched"]
+        assert call["mode"] == "async" or call["prefetched"] == 0
+
+
 def test_serving_engine_spans():
     """A tick's spans: schedule, prefill_batch holding prefill_forward,
     sample and one write_prompt per admitted request (blocks, tokens),
