@@ -162,7 +162,7 @@ def _recount_refcounts(ctx: AnalysisContext) -> dict[int, int]:
     _, output_nodes = ctx.prog.liveness()
     cons_segs: dict[int, set[int]] = {}
     for seg in ctx.schedule.segments:
-        for slot in seg.inputs:
+        for slot in seg.read_slots:
             cons_segs.setdefault(slot[0], set()).add(seg.sid)
     rc = {p: len(s) for p, s in cons_segs.items()}
     for p in output_nodes:
@@ -259,7 +259,7 @@ def deadlock_pass(ctx: AnalysisContext, rep: DiagnosticReport) -> None:
 
     adj: list[set[int]] = [set() for _ in range(n)]
     for i, seg in enumerate(segs):
-        for slot in seg.inputs:
+        for slot in seg.read_slots:
             j = produced_at.get(slot)
             if j is None or j == i:
                 continue        # root/undefined: liveness pass reports
@@ -398,7 +398,7 @@ def abstract_interpret(ctx: AnalysisContext) -> InterpResult:
             slots_by_producer.setdefault(slot[0], []).append(slot)
     readers: dict[Slot, list[tuple[int, int]]] = {}
     for i, seg in enumerate(segs):
-        for slot in seg.inputs:
+        for slot in seg.read_slots:
             readers.setdefault(slot, []).append((i, seg.device))
 
     refcount = dict(sched.node_refcount)
@@ -406,11 +406,72 @@ def abstract_interpret(ctx: AnalysisContext) -> InterpResult:
     produced: set[Slot] = set()
     freed: set[Slot] = set()
     donated: set[Slot] = set()
-    cache: set[tuple[Slot, int]] = set()
-    ever_transferred: set[tuple[Slot, int]] = set()
-    cache_by_src: dict[int, list[tuple[Slot, int]]] = {}
+    # cached transferred copies -> their bytes: (slot, pe) for a whole
+    # copy, (slot, pe, index) for a view
+    cache: dict[tuple, float] = {}
+    ever_transferred: set[tuple] = set()
+    cache_by_src: dict[int, list[tuple]] = {}
     n_freed = 0
     n_transfers = 0
+
+    def readable(seg, slot: Slot) -> bool:
+        """The source value of a read exists and is still live."""
+        src = slot[0]
+        if src in roots:
+            pass
+        elif src not in prog_nodes:
+            emit(E.RP013_UNDEFINED_VALUE, ERROR,
+                 f"segment {seg.sid} reads slot {slot}, whose producer "
+                 f"is neither a program node nor an input/const",
+                 node=src, segment=seg.sid)
+            return False
+        elif slot not in produced:
+            if slot not in produced_at:
+                emit(E.RP013_UNDEFINED_VALUE, ERROR,
+                     f"segment {seg.sid} consumes slot {slot} "
+                     f"that no segment exports", node=src,
+                     segment=seg.sid)
+            # produced later: deadlock pass owns RP010
+            return False
+        elif slot in freed:
+            emit(E.RP001_USE_AFTER_FREE, ERROR,
+                 f"segment {seg.sid} reads slot {slot} after the "
+                 f"refcount schedule freed it (producer refcount "
+                 f"reached zero too early)", node=src,
+                 segment=seg.sid)
+            return False
+        if slot in donated:
+            emit(E.RP003_BAD_DONATION, ERROR,
+                 f"segment {seg.sid} reads slot {slot} after an "
+                 f"earlier segment donated its buffer to XLA",
+                 node=src, segment=seg.sid)
+            return False
+        return True
+
+    def ship(seg, key: tuple, nb: float, src_pe: int,
+             dies_here: bool) -> float:
+        """One lazily issued copy into the transfer cache; returns the
+        bytes that die inside the segment."""
+        nonlocal n_transfers
+        if key in ever_transferred:
+            emit(E.RP030_REDUNDANT_TRANSFER, WARN,
+                 f"slot {key[0]} is shipped to pe {seg.device} "
+                 f"a second time (its earlier copy was "
+                 f"donated or freed before this reader)",
+                 node=key[0][0], segment=seg.sid, device=seg.device)
+        ever_transferred.add(key)
+        n_transfers += 1
+        if len(key) == 3:
+            # a view: the slice is cut on the source's pe, live there
+            # until its copy is issued
+            alloc(src_pe, nb)
+            free_b(src_pe, nb)
+        alloc(seg.device, nb)
+        if dies_here:
+            return nb
+        cache[key] = nb
+        cache_by_src.setdefault(key[0][0], []).append(key)
+        return 0.0
 
     for i, seg in enumerate(segs):
         transfer_pos = set(seg.transfer_inputs)
@@ -422,75 +483,65 @@ def abstract_interpret(ctx: AnalysisContext) -> InterpResult:
                      f"segment {seg.sid} donates input position {p}, out "
                      f"of range for its {len(seg.inputs)} inputs",
                      segment=seg.sid)
+        for p in seg.dead_views:
+            if p < 0 or p >= len(seg.views):
+                emit(E.RP003_BAD_DONATION, ERROR,
+                     f"segment {seg.sid} donates view position {p}, out "
+                     f"of range for its {len(seg.views)} views",
+                     segment=seg.sid)
         # --- reads + transfer execution -----------------------------------
         for pos, slot in enumerate(seg.inputs):
             src = slot[0]
-            is_root = src in roots
-            if not is_root and src not in prog_nodes:
-                emit(E.RP013_UNDEFINED_VALUE, ERROR,
-                     f"segment {seg.sid} reads slot {slot}, whose producer "
-                     f"is neither a program node nor an input/const",
-                     node=src, segment=seg.sid)
+            if not (src in roots or src in prog_nodes):
+                readable(seg, slot)             # reports the undefined read
                 continue
             crosses = ctx.dev(src) != seg.device
-            if pos in transfer_pos and not crosses:
-                emit(E.RP030_REDUNDANT_TRANSFER, WARN,
-                     f"segment {seg.sid} marks input {slot} as a transfer "
-                     f"but its producer already sits on pe {seg.device} — "
-                     f"a self-transfer", node=src, segment=seg.sid,
-                     device=seg.device)
-            if crosses and pos not in transfer_pos:
-                emit(E.RP012_MISSING_TRANSFER, ERROR,
-                     f"segment {seg.sid} on pe {seg.device} reads slot "
-                     f"{slot} from pe {ctx.dev(src)} without a transfer "
-                     f"op — the compiled segment would consume a remote "
-                     f"buffer", node=src, segment=seg.sid,
-                     device=seg.device)
-            # availability of the source value
-            if not is_root:
-                if slot not in produced:
-                    if slot not in produced_at:
-                        emit(E.RP013_UNDEFINED_VALUE, ERROR,
-                             f"segment {seg.sid} consumes slot {slot} "
-                             f"that no segment exports", node=src,
-                             segment=seg.sid)
-                    # produced later: deadlock pass owns RP010
-                    continue
-                if slot in freed:
-                    emit(E.RP001_USE_AFTER_FREE, ERROR,
-                         f"segment {seg.sid} reads slot {slot} after the "
-                         f"refcount schedule freed it (producer refcount "
-                         f"reached zero too early)", node=src,
-                         segment=seg.sid)
-                    continue
-            if slot in donated:
-                emit(E.RP003_BAD_DONATION, ERROR,
-                     f"segment {seg.sid} reads slot {slot} after an "
-                     f"earlier segment donated its buffer to XLA",
-                     node=src, segment=seg.sid)
+            if crosses != (pos in transfer_pos):
+                if crosses:
+                    emit(E.RP012_MISSING_TRANSFER, ERROR,
+                         f"segment {seg.sid} on pe {seg.device} reads slot "
+                         f"{slot} from pe {ctx.dev(src)} without a transfer "
+                         f"op — the compiled segment would consume a remote "
+                         f"buffer", node=src, segment=seg.sid,
+                         device=seg.device)
+                else:
+                    emit(E.RP030_REDUNDANT_TRANSFER, WARN,
+                         f"segment {seg.sid} marks input {slot} as a "
+                         f"transfer but its producer already sits on pe "
+                         f"{seg.device} — a self-transfer", node=src,
+                         segment=seg.sid, device=seg.device)
+            if not readable(seg, slot):
                 continue
             # transfer cache, mirroring the runtime's one-copy-per-device
             if pos in transfer_pos and crosses:
                 key = (slot, seg.device)
                 if key in cache:
                     if pos in donate_set:
-                        cache.discard(key)
-                        dying_copy_bytes += _slot_bytes(ctx, slot)
+                        dying_copy_bytes += cache.pop(key)
                 else:
-                    if key in ever_transferred:
-                        emit(E.RP030_REDUNDANT_TRANSFER, WARN,
-                             f"slot {slot} is shipped to pe {seg.device} "
-                             f"a second time (its earlier copy was "
-                             f"donated or freed before this reader)",
-                             node=src, segment=seg.sid, device=seg.device)
-                    ever_transferred.add(key)
-                    n_transfers += 1
-                    alloc(seg.device, _slot_bytes(ctx, slot))
-                    if pos in donate_set:
-                        dying_copy_bytes += _slot_bytes(ctx, slot)
-                    else:
-                        cache.add(key)
-                        cache_by_src.setdefault(src, []).append(key)
+                    dying_copy_bytes += ship(seg, key,
+                                             _slot_bytes(ctx, slot),
+                                             ctx.dev(src), pos in donate_set)
+        # --- view reads: the slice alone crosses, at this reader ----------
+        dead_views = set(seg.dead_views)
+        for vpos, view in enumerate(seg.views):
+            src = view.slot[0]
+            if not readable(seg, view.slot):
+                continue
+            if ctx.dev(src) == seg.device:
+                emit(E.RP030_REDUNDANT_TRANSFER, WARN,
+                     f"segment {seg.sid} reads slot {view.slot} as a view "
+                     f"but its producer already sits on pe {seg.device} "
+                     f"— a self-transfer", node=src, segment=seg.sid,
+                     device=seg.device)
+            key = (view.slot, seg.device, view.index)
+            if key in cache:
+                if vpos in dead_views:
+                    dying_copy_bytes += cache.pop(key)
+            else:
+                dying_copy_bytes += ship(seg, key,
+                                         _slot_bytes(ctx, (view.node, 0)),
+                                         ctx.dev(src), vpos in dead_views)
         # --- donation legality of same-device buffers ---------------------
         for p in sorted(donate_set):
             if p < 0 or p >= len(seg.inputs):
@@ -531,7 +582,7 @@ def abstract_interpret(ctx: AnalysisContext) -> InterpResult:
                 alloc(seg.device, _slot_bytes(ctx, slot))
         free_b(seg.device, dying_copy_bytes)
         # --- refcount-driven freeing (the runtime's exact rule) -----------
-        for src in {s[0] for s in seg.inputs}:
+        for src in {s[0] for s in seg.read_slots}:
             if src not in refcount:
                 continue    # structure pass reports the table drift
             refcount[src] -= 1
@@ -546,8 +597,7 @@ def abstract_interpret(ctx: AnalysisContext) -> InterpResult:
             if refcount[src] == 0:
                 for key in cache_by_src.pop(src, []):
                     if key in cache:
-                        cache.discard(key)
-                        free_b(key[1], _slot_bytes(ctx, key[0]))
+                        free_b(key[1], cache.pop(key))
                         n_freed += 1
                 if src not in roots:
                     for slot in slots_by_producer.get(src, []):
@@ -698,9 +748,23 @@ def overlap_interpret(ctx: AnalysisContext) -> OverlapInterpResult:
     produced: set[Slot] = set()
     freed_env: set[Slot] = set()
     donated_env: set[Slot] = set()
-    cache: set[tuple[Slot, int]] = set()
-    cache_by_src: dict[int, list[tuple[Slot, int]]] = {}
+    # cached copies -> bytes: (slot, pe) whole, (slot, pe, index) views
+    cache: dict[tuple, float] = {}
+    cache_by_src: dict[int, list[tuple]] = {}
     refcount = dict(sched.node_refcount)
+
+    def lazy(seg, key: tuple, nb: float, dies_here: bool) -> float:
+        """A copy issued at its reader; returns the bytes that die
+        inside the segment."""
+        nonlocal inflight, peak_inflight
+        alloc(seg.device, nb)
+        if dies_here:
+            return nb
+        inflight += nb
+        peak_inflight = max(peak_inflight, inflight)
+        cache[key] = nb
+        cache_by_src.setdefault(key[0][0], []).append(key)
+        return 0.0
 
     def issue_prefetch(psid: int) -> None:
         nonlocal inflight, peak_inflight, prefetched, deferred
@@ -722,7 +786,7 @@ def overlap_interpret(ctx: AnalysisContext) -> OverlapInterpResult:
             alloc(dst, nb)
             inflight += nb
             peak_inflight = max(peak_inflight, inflight)
-            cache.add(key)
+            cache[key] = nb
             cache_by_src.setdefault(slot[0], []).append(key)
 
     issue_prefetch(-1)
@@ -734,22 +798,31 @@ def overlap_interpret(ctx: AnalysisContext) -> OverlapInterpResult:
             if pos not in transfer_pos or ctx.dev(slot[0]) == seg.device:
                 continue
             key = (slot, seg.device)
-            nb = _slot_bytes(ctx, slot)
             if key in cache:
                 if pos in donate_set:
-                    cache.discard(key)
+                    nb = cache.pop(key)
                     dying_copy_bytes += nb
                     inflight -= nb
             else:
                 # lazy issue: window-deferred or re-shipped after a free
-                alloc(seg.device, nb)
-                if pos in donate_set:
+                dying_copy_bytes += lazy(seg, key, _slot_bytes(ctx, slot),
+                                         pos in donate_set)
+        # views are never prefetched: each is cut on the source's pe and
+        # shipped at its reader
+        dead_views = set(seg.dead_views)
+        for vpos, view in enumerate(seg.views):
+            key = (view.slot, seg.device, view.index)
+            if key in cache:
+                if vpos in dead_views:
+                    nb = cache.pop(key)
                     dying_copy_bytes += nb
-                else:
-                    inflight += nb
-                    peak_inflight = max(peak_inflight, inflight)
-                    cache.add(key)
-                    cache_by_src.setdefault(slot[0], []).append(key)
+                    inflight -= nb
+                continue
+            nb = _slot_bytes(ctx, (view.node, 0))
+            src_pe = ctx.dev(view.slot[0])
+            alloc(src_pe, nb)
+            free_b(src_pe, nb)
+            dying_copy_bytes += lazy(seg, key, nb, vpos in dead_views)
         for p in donate_set:
             if 0 <= p < len(seg.inputs):
                 slot = seg.inputs[p]
@@ -762,7 +835,7 @@ def overlap_interpret(ctx: AnalysisContext) -> OverlapInterpResult:
                 alloc(seg.device, _slot_bytes(ctx, slot))
         issue_prefetch(seg.sid)
         free_b(seg.device, dying_copy_bytes)
-        for src in {s[0] for s in seg.inputs}:
+        for src in {s[0] for s in seg.read_slots}:
             if src not in refcount:
                 continue
             refcount[src] -= 1
@@ -770,8 +843,7 @@ def overlap_interpret(ctx: AnalysisContext) -> OverlapInterpResult:
                 continue
             for key in cache_by_src.pop(src, []):
                 if key in cache:
-                    cache.discard(key)
-                    nb = _slot_bytes(ctx, key[0])
+                    nb = cache.pop(key)
                     free_b(key[1], nb)
                     inflight -= nb
             if src not in roots:

@@ -543,10 +543,18 @@ def segment_cost_graph(prog, sched, g: CostGraph,
     # each transfer's seconds are charged to its first-consumer edge;
     # per-slot link latency is preserved by summing per-slot costs
     comm_of: dict[tuple[int, int], float] = {}
-    first_reader: set[tuple[tuple[int, int], int]] = set()
+    first_reader: set[tuple] = set()
     deps: set[tuple[int, int]] = set()
     for seg in sched.segments:
-        for slot in seg.inputs:
+        # (slot, copy key, bytes): whole inputs, then views at the
+        # slice node's bytes
+        reads = [(slot, (slot, seg.device),
+                  float(mem[slot[0]]) / max(prog.n_outputs.get(slot[0], 1),
+                                            1))
+                 for slot in seg.inputs]
+        reads += [(v.slot, (v.slot, seg.device, v.index), float(mem[v.node]))
+                  for v in seg.views]
+        for slot, xkey, nb in reads:
             psid = sched.producer_seg.get(slot, -1)
             if psid < 0 or psid == seg.sid:
                 continue
@@ -554,12 +562,9 @@ def segment_cost_graph(prog, sched, g: CostGraph,
             deps.add(pair)
             if sched.segments[psid].device == seg.device:
                 continue
-            xkey = (slot, seg.device)
             if xkey in first_reader:
                 continue            # cached copy: no second transfer
             first_reader.add(xkey)
-            n_out = prog.n_outputs.get(slot[0], 1)
-            nb = float(mem[slot[0]]) / max(n_out, 1)
             comm_of[pair] = comm_of.get(pair, 0.0) + \
                 device_model.transfer_seconds(nb)
     for psid, sid in sorted(deps):

@@ -18,6 +18,12 @@ device compiled subprograms with explicit transfers.
   counted (count/bytes/modelled seconds) in :class:`RuntimeStats`; with
   a telemetry sink on, each is a ``runtime/transfer`` span carrying its
   ``bytes``, ``src`` and ``dst`` pe and whether it was ``prefetched``.
+* A view input (``Segment.views``: a cross-device slot read only by
+  ``__scan_slice__`` nodes) is cut on the source's device by a compiled
+  slicer and only the slice is shipped, lazily at its reader in both
+  modes; its span carries ``index``. ``RuntimeStats.view_transfers`` /
+  ``view_transfer_bytes`` count them, and ``transfers`` /
+  ``transfer_bytes`` include them.
 * Segment inputs that die at their segment (``Segment.dead_inputs``)
   are donated to XLA so the output can reuse the input buffer.
 
@@ -71,7 +77,8 @@ from ..obs import spans as _obs
 from .costmodel import DeviceModel
 from .errors import PlanValidationError
 from .executor import TracedProgram, validate_device_count
-from .segments import Segment, SegmentSchedule, Slot, cut_segments
+from .segments import (SCAN_SLICE, Segment, SegmentSchedule, Slot,
+                       cut_segments)
 
 #: Default cap on live prefetched-transfer bytes (the in-flight window).
 #: A prefetch that would push the live transferred-copy total past this
@@ -116,6 +123,8 @@ class RuntimeStats:
     prefetched_transfers: int = 0      # issued at producer dispatch
     deferred_transfers: int = 0        # prefetches pushed past the window
     transfer_bytes: float = 0.0
+    view_transfers: int = 0            # slices shipped in place of stacks
+    view_transfer_bytes: float = 0.0
     transfer_seconds_modeled: float = 0.0
     transfer_window_bytes: float = 0.0
     peak_inflight_transfer_bytes: float = 0.0   # live transferred copies
@@ -146,6 +155,8 @@ class RuntimeStats:
             "prefetched_transfers": int(self.prefetched_transfers),
             "deferred_transfers": int(self.deferred_transfers),
             "transfer_bytes": float(self.transfer_bytes),
+            "view_transfers": int(self.view_transfers),
+            "view_transfer_bytes": float(self.view_transfer_bytes),
             "transfer_seconds_modeled": float(self.transfer_seconds_modeled),
             "transfer_window_bytes": float(self.transfer_window_bytes),
             "peak_inflight_transfer_bytes":
@@ -185,11 +196,16 @@ def _nbytes(v: Any) -> int:
 
 def _make_segment_fn(prog: TracedProgram, seg: Segment):
     """Build the python callable replaying ``seg``'s nodes; ``jax.jit``
-    of this function is the segment's compiled subprogram."""
+    of this function is the segment's compiled subprogram. Its arguments
+    are ``seg.inputs`` then one slice per ``seg.views`` entry, which
+    stands in for the slice nodes that read it."""
     input_slots = seg.inputs
+    n_in = len(input_slots)
 
     def fn(*invals):
         env: dict[Slot, Any] = dict(zip(input_slots, invals))
+        views = {(v.slot, v.index): x
+                 for v, x in zip(seg.views, invals[n_in:])}
         local: dict[int, Any] = {}
 
         def read(src: int, idx: int):
@@ -200,9 +216,15 @@ def _make_segment_fn(prog: TracedProgram, seg: Segment):
 
         for nid in seg.nodes:
             prim, params, inputs = prog.program[nid]
+            if prim == SCAN_SLICE and views:
+                view = views.get(((inputs[0][1], inputs[0][2]),
+                                  params["index"]))
+                if view is not None:
+                    local[nid] = view
+                    continue
             vals = [inp[1] if inp[0] == "lit" else read(inp[1], inp[2])
                     for inp in inputs]
-            if prim == "__scan_slice__":
+            if prim == SCAN_SLICE:
                 out = vals[0][params["index"]]
             elif prim == "__scan_stack__":
                 out = jnp.stack(vals)
@@ -277,19 +299,30 @@ class CompiledRuntime:
             fn = _make_segment_fn(prog, seg)
             dn = self._effective_donations(seg, prog_nodes,
                                            output_nodes) if donate else ()
-            self._donate_sets.append(frozenset(dn))
-            self._jits.append(jax.jit(fn, donate_argnums=dn))
+            # a view copy is a fresh slice: its last reader on the
+            # device may always donate it, aliased devices included
+            vdn = tuple(len(seg.inputs) + p for p in seg.dead_views) \
+                if donate else ()
+            self._donate_sets.append(frozenset(dn + vdn))
+            self._jits.append(jax.jit(fn, donate_argnums=dn + vdn))
         self._compiled: dict[int, Any] = {}
+        # (view slot, index) -> compiled slicer on the source's device
+        self._slicers: dict[tuple[Slot, int], Any] = {}
         # slots whose env value is donated by some consumer (same-device
         # or aliased reads) and transfer-copy keys donated by their last
         # reader: the timeline sweep must not retain those buffers —
         # XLA deletes them when the donating segment executes
         self._donated_env_slots: set[Slot] = set()
-        self._donated_copy_keys: set[tuple[Slot, int]] = set()
+        self._donated_copy_keys: set[tuple] = set()
         for seg, dset in zip(self.schedule.segments, self._donate_sets):
             seg_dev = self.devices[seg.device]
             tpos = set(seg.transfer_inputs)
             for pos in dset:
+                if pos >= len(seg.inputs):
+                    v = seg.views[pos - len(seg.inputs)]
+                    self._donated_copy_keys.add((v.slot, seg.device,
+                                                 v.index))
+                    continue
                 slot = seg.inputs[pos]
                 if pos in tpos and self._dev_of(slot[0]) is not seg_dev:
                     self._donated_copy_keys.add((slot, seg.device))
@@ -309,7 +342,7 @@ class CompiledRuntime:
         for seg in self.schedule.segments:
             for slot in seg.outputs:
                 self._slots_by_producer.setdefault(slot[0], []).append(slot)
-            for slot in seg.inputs:
+            for slot in seg.read_slots:
                 if slot[0] in roots and slot not in seen_root:
                     seen_root.add(slot)
                     self._root_slots.append(slot)
@@ -410,17 +443,24 @@ class CompiledRuntime:
         placed inputs and constants (``node_vals``) through
         ``jax.eval_shape`` of the segments before it, each is lowered for
         its device, and the compiles run in a pool of threads (XLA
-        compiles without holding the GIL). Returns the seconds."""
+        compiles without holding the GIL). Each view's slicer is
+        compiled beside them, once per (shape, dtype, index, source
+        device). Returns the seconds."""
         t0 = time.perf_counter()
         avals: dict[Slot, Any] = {
             (nid, 0): jax.ShapeDtypeStruct(v.shape, v.dtype,
                                            weak_type=v.aval.weak_type)
             for nid, v in node_vals.items()}
         lowered = []
+        # slicer signature -> view keys it serves
+        slicer_users: dict[tuple, list[tuple[Slot, int]]] = {}
 
         def compile_one(item):
-            sid, lw = item
-            with _obs.span("runtime/compile", "runtime", segment=sid):
+            key, lw = item
+            if isinstance(key, int):
+                with _obs.span("runtime/compile", "runtime", segment=key):
+                    return lw.compile()
+            with _obs.span("runtime/compile", "runtime", index=key[3]):
                 return lw.compile()
 
         with warnings.catch_warnings():
@@ -434,18 +474,39 @@ class CompiledRuntime:
                                             weak_type=avals[s].weak_type,
                                             sharding=sharding)
                        for s in seg.inputs]
+                for v in seg.views:
+                    a = avals[v.slot]
+                    ins.append(jax.ShapeDtypeStruct(
+                        a.shape[1:], a.dtype, weak_type=a.weak_type,
+                        sharding=sharding))
+                    if (v.slot, v.index) not in self._slicers:
+                        sig = (a.shape, a.dtype, a.weak_type, v.index,
+                               self._pe_of(v.slot[0]))
+                        slicer_users.setdefault(sig, []).append(
+                            (v.slot, v.index))
                 outs = jax.eval_shape(jitted, *ins)
                 for slot, o in zip(seg.outputs, outs):
                     avals[slot] = o
                 if seg.sid not in self._compiled:
                     lowered.append((seg.sid, jitted.lower(*ins)))
+            for sig in slicer_users:
+                shape, dtype, weak, index, pe = sig
+                src = jax.ShapeDtypeStruct(
+                    shape, dtype, weak_type=weak,
+                    sharding=SingleDeviceSharding(self.devices[pe]))
+                lowered.append((sig, jax.jit(
+                    lambda x, i=index: x[i]).lower(src)))
             # a few threads: each compile also takes the persistent
             # cache's file lock, which more threads only queue on
             workers = max(1, min(len(lowered), os.cpu_count() or 1, 8))
             with ThreadPoolExecutor(workers) as pool:
-                for (sid, _), exe in zip(lowered,
+                for (key, _), exe in zip(lowered,
                                          pool.map(compile_one, lowered)):
-                    self._compiled[sid] = exe
+                    if isinstance(key, int):
+                        self._compiled[key] = exe
+                    else:
+                        for view_key in slicer_users[key]:
+                            self._slicers[view_key] = exe
         return time.perf_counter() - t0
 
     def _run(self, flat_args: list, sync: bool, window: float,
@@ -466,6 +527,8 @@ class CompiledRuntime:
         st.prefetched_transfers = 0
         st.deferred_transfers = 0
         st.transfer_bytes = 0.0
+        st.view_transfers = 0
+        st.view_transfer_bytes = 0.0
         st.transfer_seconds_modeled = 0.0
         st.transfer_window_bytes = window
         st.peak_inflight_transfer_bytes = 0.0
@@ -492,19 +555,33 @@ class CompiledRuntime:
         for slot in self._root_slots:
             env[slot] = node_vals[slot[0]]
 
-        # transferred copies, one per (slot, target pe), live until their
-        # last reader on that device donates them or the source is freed
-        xfer_cache: dict[tuple[Slot, int], Any] = {}
-        cache_by_src: dict[int, list[tuple[Slot, int]]] = {}
+        # transferred copies, one per (slot, target pe) and one per
+        # (slot, target pe, index) for views, live until their last
+        # reader on that device donates them or the source is freed
+        xfer_cache: dict[tuple, Any] = {}
+        cache_by_src: dict[int, list[tuple]] = {}
 
         def put(v: Any, nb: float, src_pe: int, dst_pe: int,
-                prefetched: bool) -> Any:
-            """One cross-device copy, in a ``runtime/transfer`` span."""
+                prefetched: bool, index: int | None = None) -> Any:
+            """One cross-device copy, in a ``runtime/transfer`` span
+            (with the ``index`` of a view)."""
             if not traced:
                 return jax.device_put(v, self.devices[dst_pe])
+            args = {} if index is None else {"index": index}
             with _obs.span("runtime/transfer", "runtime", bytes=nb,
-                           src=src_pe, dst=dst_pe, prefetched=prefetched):
+                           src=src_pe, dst=dst_pe, prefetched=prefetched,
+                           **args):
                 return jax.device_put(v, self.devices[dst_pe])
+
+        def keep_copy(key: tuple, v: Any, nb: float) -> None:
+            """Cache a lazily issued copy for later readers on its
+            device."""
+            nonlocal inflight
+            inflight += nb
+            if inflight > st.peak_inflight_transfer_bytes:
+                st.peak_inflight_transfer_bytes = inflight
+            xfer_cache[key] = v
+            cache_by_src.setdefault(key[0][0], []).append(key)
 
         def count_transfer(nb: float) -> None:
             st.transfers += 1
@@ -598,15 +675,44 @@ class CompiledRuntime:
                             if pos in donate_set:
                                 dying_copy_bytes += nb
                             else:
-                                inflight += nb
-                                if inflight > st.peak_inflight_transfer_bytes:
-                                    st.peak_inflight_transfer_bytes = inflight
-                                xfer_cache[key] = v
-                                cache_by_src.setdefault(slot[0],
-                                                        []).append(key)
+                                keep_copy(key, v, nb)
                         if self._timeline \
                                 and key not in self._donated_copy_keys:
                             xfer_vals.append(v)
+                    invals.append(v)
+                for vpos, view in enumerate(seg.views):
+                    # the source's device cuts the slice and ships it
+                    # alone, at this reader, in both modes
+                    key = (view.slot, seg.device, view.index)
+                    v = xfer_cache.get(key)
+                    donated = vpos + len(seg.inputs) in donate_set
+                    if v is not None:
+                        if donated:
+                            xfer_cache.pop(key)
+                            nb = float(_nbytes(v))
+                            dying_copy_bytes += nb
+                            inflight -= nb
+                    else:
+                        src_pe = self._pe_of(view.slot[0])
+                        v = self._slicers[(view.slot, view.index)](
+                            env[view.slot])
+                        nb = float(_nbytes(v))
+                        if self.devices[src_pe] is not dev:
+                            alloc(src_pe, nb)   # the cut, until shipped
+                            v = put(v, nb, src_pe, seg.device, False,
+                                    view.index)
+                            live[src_pe] -= nb
+                            count_transfer(nb)
+                            st.view_transfers += 1
+                            st.view_transfer_bytes += nb
+                        alloc(seg.device, nb)
+                        if donated:
+                            dying_copy_bytes += nb
+                        else:
+                            keep_copy(key, v, nb)
+                    if self._timeline \
+                            and key not in self._donated_copy_keys:
+                        xfer_vals.append(v)
                     invals.append(v)
                 exe = self._compiled[seg.sid]
                 t_seg = (time.perf_counter() if self.profile_segments
@@ -641,7 +747,7 @@ class CompiledRuntime:
             live[seg.device] -= dying_copy_bytes
             # liveness-driven freeing: drop values whose last consuming
             # segment has now run (plus their cached transfer copies)
-            for src in {s[0] for s in seg.inputs}:
+            for src in {s[0] for s in seg.read_slots}:
                 if src not in refcount:
                     continue
                 refcount[src] -= 1

@@ -38,11 +38,23 @@ consumers live on another device. The runtime issues those
 *dispatched* (not completed), so the copy overlaps with compute instead
 of stalling the consumer. Entries keyed ``-1`` belong to graph
 inputs/constants and are issued at call start.
+
+**View inputs.** A cross-device read whose every reader inside the
+segment is a ``__scan_slice__`` node does not ship the stacked source:
+the source's device cuts ``source[index]`` and only that slice crosses
+(:class:`View`). The cost graph already prices the edge from a stacked
+array to its ``scan_slice_<i>`` node at the slice's bytes; the view
+makes the runtime move what the planner priced. A view copy is issued
+lazily at its reader (never prefetched) and cached per
+``(slot, pe, index)``; the last reader of a view on its device donates
+it (``Segment.dead_views``). A segment that reads the source whole
+anywhere gets the whole copy, as before.
 """
 from __future__ import annotations
 
 import heapq
 from dataclasses import dataclass, field
+from typing import NamedTuple
 
 import numpy as np
 
@@ -50,6 +62,17 @@ from .errors import RP104_DEVICE_MISMATCH, PlanValidationError
 from .executor import TracedProgram
 
 Slot = tuple[int, int]
+
+SCAN_SLICE = "__scan_slice__"
+
+
+class View(NamedTuple):
+    """A view input: ``slot[index]`` cut on the source's device and
+    shipped alone. ``node`` is the segment's first ``__scan_slice__``
+    node reading it (its output bytes are the slice's bytes)."""
+    slot: Slot
+    index: int
+    node: int
 
 
 @dataclass(frozen=True)
@@ -66,6 +89,21 @@ class Segment:
     dead_inputs: tuple[int, ...] = ()
     # input positions whose producer lives on another device (transfers)
     transfer_inputs: tuple[int, ...] = ()
+    # cross-device slots read only by __scan_slice__ nodes here: one
+    # argument per (slot, index) after ``inputs``, standing in for the
+    # slice nodes that read it; the stacked slot is not in ``inputs``
+    views: tuple[View, ...] = ()
+    # view positions whose last reader on this device is this segment
+    dead_views: tuple[int, ...] = ()
+
+    @property
+    def read_slots(self) -> tuple[Slot, ...]:
+        """Every external slot this segment reads: ``inputs`` and the
+        sources of ``views`` (what its refcounts and dependencies
+        follow)."""
+        if not self.views:
+            return self.inputs
+        return self.inputs + tuple(dict.fromkeys(v.slot for v in self.views))
 
 
 @dataclass
@@ -78,7 +116,7 @@ class SegmentSchedule:
     node_refcount: dict[int, int] = field(default_factory=dict)
     # producer node -> last consuming segment id (-1: only program output)
     last_consumer_seg: dict[int, int] = field(default_factory=dict)
-    num_transfer_edges: int = 0          # static cross-device slot reads
+    num_transfer_edges: int = 0          # static cross-device reads (views too)
     # producing segment id -> transfers to issue right after its dispatch
     # (-1: transfers of graph inputs/consts, issued at call start); one
     # entry per (slot, dst pe), ordered by first consumer
@@ -209,23 +247,43 @@ def cut_segments(prog: TracedProgram, assignment: np.ndarray | None,
     # runtime caches one transferred copy per target device and only the
     # final reader there may donate it
     last_on_dev: dict[tuple[Slot, int], int] = {}
+    # (slot, consuming pe, index) -> last segment there reading that view
+    last_view_on_dev: dict[tuple[Slot, int, int], int] = {}
+    seg_views: list[list[View]] = []
     for sid, run in enumerate(runs):
         run_set = set(run)
         sdev = dev(run[0])
-        in_slots: list[Slot] = []
-        seen: set[Slot] = set()
+        # external slot -> every reader in this run is a scan slice
+        only_sliced: dict[Slot, bool] = {}
         for nid in run:
+            prim = prog.program[nid][0]
             for inp in prog.program[nid][2]:
-                if inp[0] != "slot":
+                if inp[0] != "slot" or inp[1] in run_set:
                     continue
-                src, idx = inp[1], inp[2]
-                if src in run_set:
-                    continue
-                slot = (src, idx)
-                if slot not in seen:
-                    seen.add(slot)
-                    in_slots.append(slot)
-                    last_on_dev[(slot, sdev)] = sid
+                slot = (inp[1], inp[2])
+                only_sliced[slot] = (only_sliced.get(slot, True)
+                                     and prim == SCAN_SLICE)
+        in_slots: list[Slot] = []
+        view_slots: set[Slot] = set()
+        for slot, sliced in only_sliced.items():
+            if sliced and dev(slot[0]) != sdev:
+                view_slots.add(slot)    # shipped as views, below
+            else:
+                in_slots.append(slot)
+                last_on_dev[(slot, sdev)] = sid
+        views: dict[tuple[Slot, int], View] = {}
+        for nid in run:
+            prim, params, ins = prog.program[nid]
+            if prim != SCAN_SLICE:
+                continue
+            slot = (ins[0][1], ins[0][2])
+            if slot not in view_slots:
+                continue
+            vkey = (slot, int(params["index"]))
+            if vkey not in views:
+                views[vkey] = View(slot, vkey[1], nid)
+                last_view_on_dev[(slot, sdev, vkey[1])] = sid
+        seg_views.append(list(views.values()))
         out_slots: list[Slot] = []
         for nid in run:
             n_out = prog.n_outputs.get(nid, 1)
@@ -279,16 +337,21 @@ def cut_segments(prog: TracedProgram, assignment: np.ndarray | None,
                 # same-device intermediate whose last reader is this
                 # segment — freed right after, safe to donate
                 dead.append(pos)
+        views = seg_views[sid]
+        num_transfers += len(views)
+        dead_views = [vpos for vpos, v in enumerate(views)
+                      if last_view_on_dev[(v.slot, sdev, v.index)] == sid]
         segments.append(Segment(
             sid=sid, device=sdev, nodes=tuple(run),
             inputs=tuple(seg_inputs[sid]), outputs=tuple(seg_outputs[sid]),
-            dead_inputs=tuple(dead), transfer_inputs=tuple(transfers)))
+            dead_inputs=tuple(dead), transfer_inputs=tuple(transfers),
+            views=tuple(views), dead_views=tuple(dead_views)))
 
     producer_seg: dict[Slot, int] = {}
     for seg in segments:
         for slot in seg.outputs:
             producer_seg[slot] = seg.sid
-        for slot in seg.inputs:
+        for slot in seg.read_slots:
             producer_seg.setdefault(slot, seg_of_node.get(slot[0], -1))
 
     return SegmentSchedule(segments=segments, k=k,

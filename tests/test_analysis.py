@@ -233,3 +233,59 @@ def test_cli_exit_codes(tmp_path, traced):
     with open(out) as f:
         rep = json.load(f)
     assert any(d["code"] == "RP032" for d in rep["diagnostics"])
+
+
+# ------------------------------------------------------------ view inputs
+@pytest.fixture(scope="module")
+def views():
+    """The runtime tests' placed double scan: a stack on pe 1 whose
+    layer-6 slice is read on pe 2 and layer-7 slices on pe 3."""
+    from test_runtime import VIEWS_SETUP
+    ns: dict = {}
+    exec(VIEWS_SETUP, ns)
+    return ns
+
+
+def test_a_program_with_view_inputs_verifies_clean(views):
+    from repro.analysis import analyze
+    from repro.core.segments import cut_segments
+    g, prog, a, w, x, off = views["placed"]()
+    assert sum(len(s.views) for s in cut_segments(prog, a, 4).segments) == 2
+    rep = analyze(prog, a, 4, graph=g)
+    assert not rep.diagnostics, rep.render()
+    for name in ("structure", "deadlock", "liveness", "memory", "overlap"):
+        assert name in rep.passes_run
+
+
+def test_the_certificate_charges_a_view_at_the_slice_bytes(views):
+    """On the reader's pe the certificate holds the slice, the carry in
+    and the product out: three slices' bytes, never the stack's eight.
+    A whole reader beside the layer-7 slices brings the stack back."""
+    from repro.analysis import certified_peaks
+    g, prog, a, w, x, off = views["placed"]()
+    slice_nb = w.nbytes // views["L"]
+    cert = certified_peaks(prog, a, 4, g)
+    assert cert[2] == 3 * slice_nb and cert[3] == 4 * slice_nb, cert
+    g, prog, a, w, x, off = views["placed"](whole=True)
+    assert certified_peaks(prog, a, 4, g)[3] > w.nbytes
+
+
+@pytest.mark.parametrize("mode", ["sync", "async"])
+def test_the_lazy_certificate_bounds_the_runtime_with_views(mode):
+    from repro.conformance import run_py
+    from test_runtime import VIEWS_SETUP
+    run_py(VIEWS_SETUP + f"""
+from repro.analysis.passes import AnalysisContext, abstract_interpret
+from repro.core.runtime import CompiledRuntime
+
+for whole in (False, True):
+    g, prog, a, w, x, off = placed(whole)
+    rt = CompiledRuntime(prog, a, jax.devices(), mode={mode!r})
+    rt(w, x)
+    ctx = AnalysisContext(prog=prog, assignment=a, k=4,
+                          schedule=rt.schedule, graph=g)
+    cert = abstract_interpret(ctx).cert_peaks
+    peaks = np.asarray(rt.stats.peak_live_bytes)
+    assert np.all(cert >= peaks), (whole, cert, peaks)
+print('OK')
+""")

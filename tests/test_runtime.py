@@ -129,7 +129,7 @@ def test_segment_refcounts_match_consumption():
     seg_of = {n: s.sid for s in sched.segments for n in s.nodes}
     for src, rc in sched.node_refcount.items():
         consuming = {s.sid for s in sched.segments
-                     if any(sl[0] == src for sl in s.inputs)}
+                     if any(sl[0] == src for sl in s.read_slots)}
         expect = len(consuming) + (1 if src in prog.output_nodes else 0)
         assert rc == expect, (src, rc, expect)
         if consuming:
@@ -559,3 +559,127 @@ def test_measured_timeline_on_four_host_devices():
         assert rt.stats.done_seconds == []
         print('OK segments', n)
     """)
+
+
+# ------------------------------------------------------------ view inputs
+#: A stacked weight ``w`` [L, D] on pe 1, scanned twice over ``x``. The
+#: first scan's layer-6 slice sits on pe 2 and both layer-7 slices on pe
+#: 3, each with its multiply; every other node stays on pe 1. With
+#: ``whole=True`` the output is scaled by ``max(w)``, a reader of the
+#: whole stack placed on pe 3 beside the layer-7 slices. Multiplies and
+#: a max round the same in any placement, so outputs are bit-equal.
+VIEWS_SETUP = """
+import jax, jax.numpy as jnp, numpy as np
+from repro.core.tracing import trace_cost_graph
+
+L, D = 8, 64
+
+def scan_twice(w, x, whole):
+    def body(h, wi):
+        return h * wi, None
+    h, _ = jax.lax.scan(body, x, w)
+    h, _ = jax.lax.scan(body, h, w)
+    return h * jnp.max(w) if whole else h
+
+def placed(whole=False):
+    w = jax.random.normal(jax.random.PRNGKey(0), (L, D))
+    x = jax.random.normal(jax.random.PRNGKey(1), (D,))
+    g, prog = trace_cost_graph(lambda w, x: scan_twice(w, x, whole), w, x,
+                               record=True)
+    consumers, _ = prog.liveness()
+    w_node = prog.input_nodes[0]
+    a = np.ones(g.n, dtype=np.int64)
+    seen = set()
+    for nid in sorted(prog.program):
+        prim, params, ins = prog.program[nid]
+        if prim == '__scan_slice__':
+            i = params['index']
+            pe = {6: None if i in seen else 2, 7: 3}.get(i)
+            seen.add(i)
+            if pe is not None:
+                a[nid] = pe
+                for c in consumers[nid]:
+                    a[c] = pe
+        elif any(inp[0] == 'slot' and inp[1] == w_node for inp in ins):
+            a[nid] = 3              # the whole-stack reader
+    if whole:
+        a[max(prog.program)] = 3    # the final scaling
+    # the slices placed off the stack's pe: (index, pe) per slice node
+    off = [(prog.program[n][1]['index'], int(a[n])) for n in prog.program
+           if prog.program[n][0] == '__scan_slice__' and a[n] != a[w_node]]
+    return g, prog, a, w, x, off
+"""
+
+
+@pytest.mark.parametrize("mode", ["sync", "async"])
+def test_view_transfers_ship_only_the_slices_on_four_host_devices(mode):
+    """A segment whose only readers of a remote stack are slices gets
+    the slices, not the stack: outputs bit-equal to one device, bytes
+    moved are the slices' (and the carries'), one copy per distinct
+    (source, index, device), and a second call compiles nothing."""
+    run_py(VIEWS_SETUP + f"""
+from jax import monitoring
+from repro.core.runtime import CompiledRuntime
+
+g, prog, a, w, x, off = placed()
+assert sorted(off) == [(6, 2), (7, 3), (7, 3)], off
+one = CompiledRuntime(prog, None, jax.devices()[:1])(w, x)
+rt = CompiledRuntime(prog, a, jax.devices(), mode={mode!r})
+out = rt(w, x)
+assert np.array_equal(np.asarray(out), np.asarray(one))
+st = rt.stats
+slice_nb = w.nbytes // L
+assert st.view_transfers == len(set(off)) == 2, st.view_transfers
+assert st.view_transfer_bytes == 2 * slice_nb
+# the carry crosses pe 1 -> 2 -> 3 -> 1 -> 3; the stack never moves
+assert st.transfer_bytes == 2 * slice_nb + 4 * x.nbytes, st.transfer_bytes
+assert st.transfers == 2 + 4
+assert st.to_dict()['view_transfers'] == 2
+
+compiles = []
+monitoring.register_event_duration_secs_listener(
+    lambda event, secs, **kw: compiles.append(event)
+    if event == '/jax/core/compile/backend_compile_duration' else None)
+compile_s = st.compile_seconds
+out2 = rt(w, x)
+assert compiles == [] and rt.stats.compile_seconds == compile_s
+assert np.array_equal(np.asarray(out2), np.asarray(one))
+assert rt.stats.view_transfers == 2
+print('OK')
+""")
+
+
+def test_a_whole_reader_in_the_segment_ships_the_whole_stack():
+    """Where another node of the segment reads the stack whole, the
+    stack is copied whole, as before; the other device still gets its
+    slice alone."""
+    run_py(VIEWS_SETUP + """
+from repro.core.runtime import CompiledRuntime
+
+g, prog, a, w, x, off = placed(whole=True)
+one = CompiledRuntime(prog, None, jax.devices()[:1])(w, x)
+rt = CompiledRuntime(prog, a, jax.devices(), mode='sync')
+out = rt(w, x)
+assert np.array_equal(np.asarray(out), np.asarray(one))
+st = rt.stats
+assert st.view_transfers == 1                  # layer 6 to pe 2
+assert st.transfer_bytes == w.nbytes + w.nbytes // L + 4 * x.nbytes
+seg3 = [s for s in rt.schedule.segments if s.device == 3]
+assert any((prog.input_nodes[0], 0) in s.inputs for s in seg3)
+assert not any(s.views for s in seg3)
+print('OK')
+""")
+
+
+def test_views_on_aliased_devices_cut_locally_and_ship_nothing():
+    """On one physical device the slices are still cut (the segment
+    takes them as arguments), but nothing crosses a device."""
+    ns: dict = {}
+    exec(VIEWS_SETUP, ns)
+    g, prog, a, w, x, off = ns["placed"]()
+    assert any(s.views for s in cut_segments(prog, a, 4).segments)
+    rt = CompiledRuntime(prog, a, [jax.devices()[0]] * 4)
+    out = rt(w, x)
+    one = CompiledRuntime(prog, None, jax.devices()[:1])(w, x)
+    assert np.array_equal(np.asarray(out), np.asarray(one))
+    assert rt.stats.view_transfers == 0 and rt.stats.transfers == 0
